@@ -41,6 +41,7 @@ import numpy as np
 
 from benchmarks.common import Csv
 from benchmarks.bench_fleet import synthetic_trace
+from repro import runtime
 from repro.core import HabitatPredictor
 from repro.serve.fleet import FleetPlanner
 from repro.serve.http import PredictionClient
@@ -54,8 +55,10 @@ _BATCH = 32
 def _spawn(mod: str, extra: List[str], readiness: str
            ) -> Tuple[subprocess.Popen, str]:
     """Launch ``python -m mod`` and parse its readiness line for the
-    bound address (``--port 0`` everywhere: no port races)."""
-    env = dict(os.environ)
+    bound address (``--port 0`` everywhere: no port races).  Neither the
+    cache server nor an MLP-free worker runs a JAX computation, so each
+    is kept off the TPU chips (``runtime.worker_envs``)."""
+    env = runtime.worker_envs(1, uses_device=False)[0]
     src = str(Path(__file__).resolve().parents[1] / "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)

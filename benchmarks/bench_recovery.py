@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Tuple
 
 from benchmarks.common import Csv
 from benchmarks.bench_fleet import synthetic_trace
+from repro import runtime
 from repro.core import HabitatPredictor
 from repro.launch.serve import WorkerSupervisor, _worker_env
 from repro.serve.http import PredictionClient, PredictionServer
@@ -133,7 +134,7 @@ def _phase_ab(csv: Csv, smoke: bool) -> None:
 
     tmp = Path(tempfile.mkdtemp(prefix="bench-recovery-"))
     snap_path = tmp / "worker-0.snap"
-    env = _worker_env()
+    env = runtime.worker_envs(1, uses_device=False, base=_worker_env())[0]
     env["REPRO_SNAPSHOT_INTERVAL_S"] = "0.2"
     # pin the adaptive coalescing window: under this bench's solo traffic
     # it would stretch to REPRO_WINDOW_MAX_MS (25 ms) and bury the

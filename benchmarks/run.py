@@ -21,6 +21,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from benchmarks.common import Csv  # noqa: E402
+from repro import runtime  # noqa: E402
 
 BENCHES = [
     ("table1", "benchmarks.bench_table1_datasets",
@@ -90,6 +91,7 @@ def main() -> None:
                          "workflow uploads this as an artifact so "
                          "prediction-error regressions are trackable")
     args = ap.parse_args()
+    runtime.use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if only:
         unknown = only - {key for key, _, _ in BENCHES}

@@ -994,6 +994,9 @@ class FusedMLPScorer:
         self.mlps = dict(mlps)                # normalization + output contract
         self.block_m = block_m
         self.impl = impl
+        #: the JAX devices this scorer's outputs were computed on, one
+        #: entry per device that ran at least one of its launches
+        self.ran_on: set = set()
         # the row-mapped path standardizes per row via these stacked
         # normalization constants (one vectorized expression, elementwise
         # identical to per-kind normalize()); MLPs with an overridden
@@ -1007,6 +1010,11 @@ class FusedMLPScorer:
                 [np.asarray(mlps[k].feature_mean) for k in self.kinds])
             self._feat_std = np.stack(
                 [np.asarray(mlps[k].feature_std) for k in self.kinds])
+
+    def _host(self, out) -> np.ndarray:
+        """A launch's output on the host, noting the device it ran on."""
+        self.ran_on.update(out.devices())
+        return np.asarray(out)
 
     def score_ms(self, feats_by_kind: Dict[str, np.ndarray]
                  ) -> Dict[str, np.ndarray]:
@@ -1044,7 +1052,7 @@ class FusedMLPScorer:
                                    np.float32))
             kind_of_block.extend([0] * pad_blocks)
         SCORER_DISPATCHES.bump("fused")
-        log_ms = np.asarray(kernel_ops.fused_mlp_score(
+        log_ms = self._host(kernel_ops.fused_mlp_score(
             jnp.asarray(np.concatenate(blocks)),
             jnp.asarray(np.asarray(kind_of_block, np.int32)),
             self.weights, self.biases, block_m=bm, impl=self.impl))
@@ -1129,7 +1137,7 @@ class FusedMLPScorer:
             xs = np.zeros((len(self.kinds), bpad, self.hidden), np.float32)
             for ki, rows in enumerate(rows_by_kind):
                 xs[ki, :len(rows), :xn.shape[1]] = xn[rows]
-            log_grid = np.asarray(kernel_ops.fused_mlp_score_stacked(
+            log_grid = self._host(kernel_ops.fused_mlp_score_stacked(
                 jnp.asarray(xs), self.weights, self.biases))
             log_ms = np.empty(m, np.float32)
             for ki, rows in enumerate(rows_by_kind):
@@ -1141,7 +1149,7 @@ class FusedMLPScorer:
             row_kinds = np.zeros(padded, np.int32)
             row_kinds[:m] = kind_ids
             xp[:m, :xn.shape[1]] = xn
-            log_ms = np.asarray(kernel_ops.fused_mlp_score_rows(
+            log_ms = self._host(kernel_ops.fused_mlp_score_rows(
                 jnp.asarray(xp), jnp.asarray(row_kinds), self.weights,
                 self.biases, block_m=bm, impl=impl))[:m]
         return self._ms_from_log_rows(log_ms, kind_ids)
@@ -1150,13 +1158,15 @@ class FusedMLPScorer:
 def _resolve_scorer(scorer, mlps: Dict):
     """Map a ``predict_sweep`` scorer spelling to a usable instance.
 
-    ``None``/"off" -> per-kind jitted forwards; "auto" -> fused Pallas
-    only on a TPU backend (CPU keeps strict parity with
-    ``predict_fleet``), silently falling back when the MLP set is not
-    architecture-uniform; an impl name ("pallas" | "interpret" | "jnp")
-    forces the fused path (and raises on non-uniform MLPs); a ready
-    :class:`FusedMLPScorer` is used as-is.  The single policy shared by
-    ``predict_sweep`` and ``HabitatPredictor`` (which only adds caching).
+    ``None``/"off" -> per-kind jitted forwards; "auto" -> the fused
+    Pallas scorer on a TPU backend (and per-kind forwards elsewhere, which
+    keeps strict parity with ``predict_fleet`` on CPU); an impl name
+    ("pallas" | "interpret" | "jnp") forces the fused path; a ready
+    :class:`FusedMLPScorer` is used as-is.  A fused scorer that cannot be
+    built (MLPs of mixed architectures) raises: on a TPU, "auto" never
+    quietly runs the per-kind forwards in the kernel's place.  The single
+    policy shared by ``predict_sweep`` and ``HabitatPredictor`` (which
+    only adds caching).
     """
     if scorer is None or scorer == "off" or not mlps:
         return None
@@ -1166,12 +1176,7 @@ def _resolve_scorer(scorer, mlps: Dict):
         import jax
         if jax.default_backend() != "tpu":
             return None
-        try:
-            return FusedMLPScorer(mlps, impl="pallas")
-        except (ValueError, AttributeError):
-            # mixed architectures, or duck-typed MLPs exposing only
-            # predict_ms: best-effort falls back to per-kind forwards
-            return None
+        return FusedMLPScorer(mlps, impl="pallas")
     if scorer in ("pallas", "interpret", "jnp"):
         return FusedMLPScorer(mlps, impl=scorer)
     raise ValueError(f"unknown scorer spelling {scorer!r}")

@@ -1,15 +1,17 @@
-"""Real wall-clock measurement of tracked ops on the host device.
+"""Real wall-clock measurement of tracked ops on the device JAX runs on.
 
 This is the genuinely *runtime-based* half of the reproduction: the paper
 measures each operation's execution time on the GPU the user already has by
-re-running it in isolation (Sec. 4.1, "Execution time").  Here the device
-the user "already has" is the container's CPU; we rebuild each tracked op
-as a standalone jitted callable with the recorded shapes and time it with
-the paper's protocol (3 discarded warm-up runs, then the average of 3
-measured runs).
+re-running it in isolation (Sec. 4.1, "Execution time").  Here that device
+is whatever this process runs JAX on — a TPU chip, or the host CPU — and a
+trace is only measured on the device its ``origin_device`` names.  Each
+tracked op is rebuilt as a standalone jitted callable with the recorded
+shapes and dtype, on operands made on the device, and timed with the paper's
+protocol (3 discarded warm-up runs, then the average of 3 measured runs).
 
-Ops we cannot faithfully rebuild in isolation fall back to the simulator
-with the cpu-host spec (and are flagged, so callers can report coverage).
+An op ``build_callable`` cannot rebuild is priced by the simulator with
+the origin's spec instead, and counts against the coverage that
+``measure_trace_inplace`` returns.
 """
 
 from __future__ import annotations
@@ -20,7 +22,6 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import devices, simulator
 from repro.core.trace import Op, TrackedTrace
@@ -53,25 +54,38 @@ def _time_callable(fn: Callable, *args) -> float:
     return (time.perf_counter() - t0) / REPS * 1e3  # ms
 
 
+@partial(jax.jit, static_argnums=(0, 1))
+def _rand_on_device(shape: Tuple[int, ...], dtype: str):
+    key = jax.random.key(0)
+    if jnp.issubdtype(jnp.dtype(dtype), jnp.floating):
+        return jax.random.normal(key, shape, dtype)
+    return jax.random.bernoulli(key, 0.5, shape).astype(dtype)
+
+
 def _rand(shape, dtype="float32"):
-    rng = np.random.default_rng(0)
-    if np.issubdtype(np.dtype(dtype), np.floating):
-        return jnp.asarray(rng.standard_normal(shape), dtype)
-    return jnp.asarray(rng.integers(0, 2, shape), dtype)
+    """Standard-normal (floats) or 0/1 (integers, bools) operand, made on
+    the device: a host float64 RNG made embedding-sized operands the
+    largest cost of a full-width trace's measurement on a TPU."""
+    return _rand_on_device(tuple(int(d) for d in shape), str(dtype))
 
 
 def build_callable(op: Op) -> Optional[Tuple[Callable, tuple]]:
     """Rebuild a representative standalone callable for ``op``."""
     p = op.params
     if op.kind == "linear":
-        a = _rand((p["m"], p["k"]))
-        b = _rand((p["k"], p["n"]))
+        a = _rand((p["m"], p["k"]), op.dtype)
+        b = _rand((p["k"], p["n"]), op.dtype)
         return jnp.matmul, (a, b)
     if op.kind == "bmm":
-        a = _rand((p["b"], p["m"], p["k"]))
-        b = _rand((p["b"], p["k"], p["n"]))
+        a = _rand((p["b"], p["m"], p["k"]), op.dtype)
+        b = _rand((p["b"], p["k"], p["n"]), op.dtype)
         return jnp.matmul, (a, b)
     if op.kind == "conv2d":
+        out_size = ((p["image"] + 2 * p["padding"] - p["kernel"])
+                    // p["stride"] + 1)
+        if (not op.in_shapes or len(op.in_shapes[0]) != 4
+                or out_size < 1):
+            return None     # not a 2-D convolution this rebuild covers
         x = _rand((p["batch"], p["in_ch"], p["image"], p["image"]))
         w = _rand((p["out_ch"], p["in_ch"], p["kernel"], p["kernel"]))
         fn = partial(jax.lax.conv_general_dilated,
@@ -99,25 +113,35 @@ def build_callable(op: Op) -> Optional[Tuple[Callable, tuple]]:
     return None
 
 
-def measure_op_ms(op: Op) -> Tuple[float, bool]:
-    """(ms, measured_for_real) for one op on the host CPU."""
+def measure_op_ms(op: Op, origin: devices.DeviceSpec) -> Tuple[float, bool]:
+    """(ms, measured_for_real) for one op on this process's device.
+
+    An op without a rebuild is simulated with the ``origin`` spec — the
+    device the trace claims — and reported as not measured."""
     built = build_callable(op)
     if built is None:
-        return simulator.op_time_ms(op, devices.CPU_HOST), False
+        return simulator.op_time_ms(op, origin), False
     fn, args = built
-    try:
-        return _time_callable(fn, *args), True
-    except Exception:
-        return simulator.op_time_ms(op, devices.CPU_HOST), False
+    return _time_callable(fn, *args), True
 
 
 def measure_trace_inplace(trace: TrackedTrace) -> float:
-    """Fill ``measured_ms`` on every op by real host measurement.
+    """Fill ``measured_ms`` on every op by timing it on this device.
 
-    Returns the fraction of iteration time covered by real measurements."""
+    Raises ``ValueError`` when ``trace.origin_device`` is not the device
+    this process runs JAX on: times taken here would be labelled as
+    another device's.  Returns the fraction of iteration time covered by
+    real measurements."""
+    here = devices.local_device()
+    if trace.origin_device != here:
+        raise ValueError(
+            f"trace origin {trace.origin_device!r} is not the device this "
+            f"process runs on ({here!r}); wallclock measurement times ops "
+            f"on the device at hand")
+    origin = devices.get(trace.origin_device)
     real_ms = total_ms = 0.0
     for op in trace.ops:
-        ms, real = measure_op_ms(op)
+        ms, real = measure_op_ms(op, origin)
         op.measured_ms = ms
         total_ms += ms * op.multiplicity
         if real:

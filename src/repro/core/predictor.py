@@ -168,6 +168,12 @@ class HabitatPredictor(_FleetTraceMixin):
             self._scorer_cache = {"key": key, "scorer": scorer or "off"}
         return self._scorer_cache["scorer"]
 
+    def built_scorer(self) -> Optional[batched.FusedMLPScorer]:
+        """The fused scorer sweeps have built so far, or None (never
+        builds one)."""
+        scorer = self._scorer_cache.get("scorer")
+        return scorer if isinstance(scorer, batched.FusedMLPScorer) else None
+
     def predict_sweep(self, traces, dests: Optional[Sequence[str]] = None,
                       scorer=None,
                       cell_mask=None) -> batched.SweepPrediction:

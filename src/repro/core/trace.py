@@ -429,6 +429,11 @@ class TrackedTrace:
     ops: List[Op]
     origin_device: str
     label: str = "iteration"
+    #: fraction of iteration time that :meth:`measure` timed for real on
+    #: the origin device (the rest was simulated); not part of the wire
+    #: format or the fingerprint
+    coverage: Optional[float] = dataclasses.field(
+        default=None, repr=False, compare=False)
     _arrays: Optional[TraceArrays] = dataclasses.field(
         default=None, repr=False, compare=False)
     _fp: Optional[str] = dataclasses.field(
@@ -568,7 +573,10 @@ class TrackedTrace:
         return TrackedTrace.from_dict(doc)
 
     def measure(self, method: str = "simulate") -> "TrackedTrace":
-        """Fill ``measured_ms`` for every op on the origin device."""
+        """Fill ``measured_ms`` for every op on the origin device:
+        ``"simulate"`` prices each op with the simulator, ``"wallclock"``
+        times it on the device this process runs on, which must be the
+        origin (see :func:`repro.core.calibration.measure_trace_inplace`)."""
         self._arrays = None  # measured_ms changes under the SoA cache
         self._fp = None
         if method == "simulate":
@@ -576,9 +584,10 @@ class TrackedTrace:
             dev = devices.get(self.origin_device)
             for op in self.ops:
                 op.measured_ms = simulator.op_time_ms(op, dev)
+            self.coverage = 0.0
         elif method == "wallclock":
             from repro.core import calibration
-            calibration.measure_trace_inplace(self)
+            self.coverage = calibration.measure_trace_inplace(self)
         else:
             raise ValueError(f"unknown measure method {method!r}")
         return self

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _mlp_kernel(x_ref, w_ref, b_ref, o_ref, h_ref):
     li = pl.program_id(1)
@@ -74,7 +72,7 @@ def fused_mlp(x: jnp.ndarray, weights: jnp.ndarray, biases: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bm, hdim), lambda bi, li: (0, bi, 0)),
         out_shape=jax.ShapeDtypeStruct((1, bsz + pad, hdim), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, hdim), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x[None], weights, biases[:, None, :])

@@ -46,6 +46,11 @@ derived from ``row_kinds`` keep it cheap:
 Present kinds accumulate ``z += where(row_kind == k, h @ W_k + b_k, 0)``
 into a VMEM scratch; each row has exactly one matching kind, so the
 masked sum is exact (adding zeros), not an approximation.
+
+Both kernels multiply at ``Precision.HIGHEST``: a TPU's default lowers a
+float32 product to one bfloat16 pass, whose 8-bit mantissa would be
+expected to move a predicted time by far more than the 1e-4 relative to
+a float64 forward that the scorer is held to.
 """
 
 from __future__ import annotations
@@ -54,8 +59,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import compat
 
 
 def bucket_blocks(n_blocks: int) -> int:
@@ -113,6 +116,7 @@ def _score_kernel(kinds_ref, x_ref, w_ref, b_ref, o_ref, h_ref):
     w = w_ref[0, 0].astype(jnp.float32)              # (H, H)
     b = b_ref[0, 0].astype(jnp.float32)              # (1, H)
     z = jax.lax.dot_general(h_ref[...], w, (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32) + b
     h_ref[...] = jnp.where(li == nl - 1, z, jax.nn.relu(z))
 
@@ -139,7 +143,7 @@ def fused_mlp_score(x: jnp.ndarray, block_kinds: jnp.ndarray,
                          f"({nb} x {block_m})")
     nl = weights.shape[1]
 
-    grid_spec = compat.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb, nl),
         in_specs=[
@@ -158,7 +162,7 @@ def fused_mlp_score(x: jnp.ndarray, block_kinds: jnp.ndarray,
         _score_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, bsz, hdim), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_kinds.astype(jnp.int32), x[None], weights,
@@ -194,6 +198,7 @@ def _score_rows_kernel(dma_ref, match_ref, kinds_ref, x_ref, w_ref, b_ref,
         w = w_ref[0, 0].astype(jnp.float32)              # (H, H)
         b = b_ref[0, 0].astype(jnp.float32)              # (1, H)
         z = jax.lax.dot_general(h_ref[...], w, (((1,), (0,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32) + b
         mask = kinds_ref[...] == kind                    # (bm, 1)
         z_ref[...] += jnp.where(mask, z, 0.0)
@@ -258,7 +263,7 @@ def fused_mlp_score_rows(x: jnp.ndarray, row_kinds: jnp.ndarray,
     row_kinds = row_kinds.astype(jnp.int32)
     dma, match = _row_kind_maps(row_kinds, nb, block_m, nk)
 
-    grid_spec = compat.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb, nl, nk),
         in_specs=[
@@ -282,7 +287,7 @@ def fused_mlp_score_rows(x: jnp.ndarray, row_kinds: jnp.ndarray,
         _score_rows_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, bsz, hdim), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(dma, match, row_kinds[:, None], x[None], weights,

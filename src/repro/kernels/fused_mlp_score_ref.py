@@ -5,6 +5,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: float32 products at full precision, as in the Pallas kernels: a TPU's
+#: default lowers them to one bfloat16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def fused_mlp_score_ref(x: jnp.ndarray, block_kinds: jnp.ndarray,
                         weights: jnp.ndarray,
@@ -19,7 +23,8 @@ def fused_mlp_score_ref(x: jnp.ndarray, block_kinds: jnp.ndarray,
     w = weights[block_kinds].astype(jnp.float32)      # (nb, L, H, H)
     b = biases[block_kinds].astype(jnp.float32)       # (nb, L, H)
     for li in range(nl):
-        z = jnp.einsum("nbh,nhk->nbk", h, w[:, li]) + b[:, li, None, :]
+        z = (jnp.einsum("nbh,nhk->nbk", h, w[:, li], precision=_HIGHEST)
+             + b[:, li, None, :])
         h = z if li == nl - 1 else jax.nn.relu(z)
     return h.reshape(bsz, hdim)[:, 0]
 
@@ -45,7 +50,7 @@ def fused_mlp_score_rows_ref(x: jnp.ndarray, row_kinds: jnp.ndarray,
     for li in range(nl):
         wl = jnp.transpose(weights[:, li].astype(jnp.float32),
                            (1, 0, 2)).reshape(hdim, nk * hdim)
-        zk = (h @ wl).reshape(-1, nk, hdim)                   # (B, K, H)
+        zk = jnp.dot(h, wl, precision=_HIGHEST).reshape(-1, nk, hdim)
         z = (jnp.take_along_axis(zk, idx, axis=1)[:, 0]
              + biases[row_kinds, li].astype(jnp.float32))
         h = z if li == nl - 1 else jax.nn.relu(z)
@@ -67,7 +72,8 @@ def fused_mlp_score_stacked_ref(xs: jnp.ndarray, weights: jnp.ndarray,
     h = xs.astype(jnp.float32)
     for li in range(nl):
         z = (jnp.einsum("kbh,khj->kbj", h,
-                        weights[:, li].astype(jnp.float32))
+                        weights[:, li].astype(jnp.float32),
+                        precision=_HIGHEST)
              + biases[:, li].astype(jnp.float32)[:, None, :])
         h = z if li == nl - 1 else jax.nn.relu(z)
     return h[..., 0]

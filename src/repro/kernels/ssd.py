@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *,
                 chunk: int):
@@ -101,7 +99,7 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, bmat: jnp.ndarray,
                                lambda b_, h_, c_: (b_, h_, c_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, lp, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x, dt4, bmat, cmat)

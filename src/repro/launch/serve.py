@@ -30,6 +30,12 @@ worker is a cache hit for all of them::
   PYTHONPATH=src python -m repro.launch.serve --serve --workers 2 \\
       --port 8100 --coalesce-ms 5
 
+Only ``--fleet-mlps`` workers run JAX computations (the MLP scorer); on
+a TPU host each of them is pinned to a chip of its own, and the launcher
+refuses to start more of them than the host has chips.  The other
+workers never touch a chip.  The launcher itself initialises no JAX
+backend before its workers are up.
+
 ``--async`` swaps each worker to the asyncio front end
 (``repro.serve.aserver``): same wire formats and admission control,
 plus SSE sweep streaming (``/sweep/stream``) and event-loop concurrency
@@ -63,7 +69,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.configs import ARCHS, get_config
+from repro.core import devices
 from repro.core.batched import env_float
 from repro.models import init_params
 from repro.models.config import smoke_config
@@ -78,12 +86,26 @@ def _worker_env() -> dict:
     return env
 
 
+def _pool_envs(args) -> List[dict]:
+    """Per-worker environments for ``--workers`` processes: ``--fleet-mlps``
+    workers score on the device and get a TPU chip each (see
+    :func:`repro.runtime.worker_envs`); the rest stay off the chips.
+    Exits with the reason when the host has too few chips."""
+    try:
+        return runtime.worker_envs(args.workers,
+                                   uses_device=args.fleet_mlps,
+                                   base=_worker_env())
+    except ValueError as e:
+        sys.exit(f"refusing to start the worker pool: {e}")
+
+
 class _Worker:
     """One supervised worker process: its launch command (port pinned
     after the first bind), the live ``Popen``, and restart accounting."""
 
-    def __init__(self, cmd: List[str]):
+    def __init__(self, cmd: List[str], env: dict):
         self.cmd = list(cmd)
+        self.env = env
         self.proc: Optional[subprocess.Popen] = None
         self.url: str = ""
         self.restarts = 0
@@ -109,13 +131,17 @@ class WorkerSupervisor:
       own graceful drain: finish in-flight, shed new with 503, exit 0)
       and stops restarting — shutdown is not a crash.
 
-    The poll period is ``REPRO_SUPERVISOR_POLL_S`` (default 0.5s)."""
+    The poll period is ``REPRO_SUPERVISOR_POLL_S`` (default 0.5s).
+    Workers run in ``env``, by default one that keeps them off the TPU
+    chips; a worker that scores on a chip is spawned with its own
+    (``_pool_envs``)."""
 
     def __init__(self, env: Optional[dict] = None,
                  poll_s: Optional[float] = None,
                  backoff_s: Optional[float] = None,
                  backoff_max_s: Optional[float] = None):
-        self.env = dict(env) if env is not None else _worker_env()
+        self.env = (dict(env) if env is not None else runtime.worker_envs(
+            1, uses_device=False, base=_worker_env())[0])
         self.poll_s = (poll_s if poll_s is not None
                        else env_float("REPRO_SUPERVISOR_POLL_S", 0.5))
         self.backoff_s = (backoff_s if backoff_s is not None
@@ -137,7 +163,7 @@ class WorkerSupervisor:
         False if it exited first.  On the first successful bind the
         actual port is pinned back into the command so every restart
         lands on the same address."""
-        w.proc = subprocess.Popen(w.cmd, env=self.env,
+        w.proc = subprocess.Popen(w.cmd, env=w.env,
                                   stdout=subprocess.PIPE, text=True)
         line = w.proc.stdout.readline()
         while line and not line.startswith("serving on "):
@@ -166,16 +192,38 @@ class WorkerSupervisor:
         except ValueError:
             pass                        # stream closed mid-iteration
 
-    def spawn(self, cmd: List[str]) -> str:
-        """Launch one worker; returns its url (exits on bind failure)."""
-        w = _Worker(cmd)
+    def spawn(self, cmd: List[str], env: Optional[dict] = None) -> str:
+        """Launch one worker (in ``env``, default the supervisor's);
+        returns its url (exits on bind failure).  Restarts reuse the
+        worker's own environment, so a pinned chip stays its chip."""
+        w = _Worker(cmd, self.env if env is None else env)
         w.backoff_s = self.backoff_s
-        if not self._launch(w):
+        try:
+            ok = self._launch(w)
+        except BaseException:
+            # interrupted while the worker starts (SIGTERM arrives as
+            # KeyboardInterrupt): it must not outlive the launcher
+            if w.proc is not None:
+                w.proc.kill()
+                w.proc.wait()
+            raise
+        if not ok:
             self.drain()
             sys.exit("a worker exited before binding its port")
         with self._lock:
             self._workers.append(w)
         return w.url
+
+    def spawn_all(self, cmds: List[List[str]], envs: List[dict]
+                  ) -> List[str]:
+        """Launch ``cmds[i]`` in ``envs[i]``, in order; returns the urls.
+        All or none: when the launcher is interrupted or a worker fails
+        to bind, the workers already up are drained, not orphaned."""
+        try:
+            return [self.spawn(cmd, env=env) for cmd, env in zip(cmds, envs)]
+        except BaseException:
+            self.drain()
+            raise
 
     def start(self) -> "WorkerSupervisor":
         """Begin the watch loop on a daemon thread."""
@@ -309,12 +357,13 @@ def serve_router(args, cache) -> None:
     the router's health sweep re-admits them automatically."""
     from repro.serve.router import FingerprintRouter, RouterServer
 
+    envs = _pool_envs(args)
     _exit_on_sigterm()
     sup = WorkerSupervisor()
-    urls = [sup.spawn(_worker_cmd(args, cache,
-                                  args.port + 1 + i if args.port else 0,
-                                  snapshot=_worker_snapshot(args, i)))
-            for i in range(args.workers)]
+    urls = sup.spawn_all(
+        [_worker_cmd(args, cache, args.port + 1 + i if args.port else 0,
+                     snapshot=_worker_snapshot(args, i))
+         for i in range(args.workers)], envs)
     sup.start()
     print(f"router fleet: {len(urls)} workers on "
           f"{', '.join(urls)} (cache: {cache})", flush=True)
@@ -407,11 +456,12 @@ def serve_http(args) -> None:
             log_engine_caches(service)
         return
 
+    envs = _pool_envs(args)
     _exit_on_sigterm()
     sup = WorkerSupervisor()
-    for i in range(args.workers):
-        sup.spawn(_worker_cmd(args, cache, args.port + i,
-                              snapshot=_worker_snapshot(args, i)))
+    sup.spawn_all([_worker_cmd(args, cache, args.port + i,
+                               snapshot=_worker_snapshot(args, i))
+                   for i in range(args.workers)], envs)
     sup.start()
     print(f"launched {args.workers} supervised workers on ports "
           f"{args.port}..{args.port + args.workers - 1} "
@@ -426,6 +476,23 @@ def serve_http(args) -> None:
         s = sup.stats()
         print(f"supervisor shutdown: workers={s['workers']} "
               f"restarts={s['restarts']}", flush=True)
+
+
+def decode_traces(cfg, params, batches, max_seq: int, tracker,
+                  arch: str) -> list:
+    """Track one decode step of a :class:`ServingEngine` per batch size
+    (labels ``<arch>-decode-b<batch>``) — the traces a what-if sweep
+    prices."""
+    from repro.models import transformer as tfm
+
+    traces = []
+    for b in batches:
+        eng = ServingEngine(cfg, params, b, max_seq)
+        traces.append(tracker.track(
+            lambda p, t, s: tfm.decode_step(p, cfg, t, s),
+            params, jnp.asarray(eng.last_token), eng.state,
+            label=f"{arch}-decode-b{b}"))
+    return traces
 
 
 def main():
@@ -493,6 +560,7 @@ def main():
                          "and restores on restart, so crash recoveries "
                          "come back warm instead of cold")
     args = ap.parse_args()
+    runtime.use_compile_cache()
 
     if args.serve or args.cache_server:
         serve_http(args)
@@ -535,7 +603,7 @@ def main():
         from repro.models import transformer as tfm
         from repro.serve.fleet import format_fleet
 
-        tracker = OperationTracker("cpu-host")
+        tracker = OperationTracker(devices.local_device())
         trace = tracker.track(
             lambda p, t, s: tfm.decode_step(p, cfg, t, s),
             params, jnp.asarray(engine.last_token), engine.state,
@@ -555,18 +623,12 @@ def main():
 
     if args.sweep or args.optimize:
         from repro.core import OperationTracker
-        from repro.models import transformer as tfm
         from repro.serve.fleet import format_sweep
 
         batches = [int(b) for b in args.sweep_batches.split(",")]
-        tracker = OperationTracker("cpu-host")
-        traces = []
-        for b in batches:
-            eng = ServingEngine(cfg, params, b, args.max_seq)
-            traces.append(tracker.track(
-                lambda p, t, s: tfm.decode_step(p, cfg, t, s),
-                params, jnp.asarray(eng.last_token), eng.state,
-                label=f"{args.arch}-decode-b{b}"))
+        traces = decode_traces(
+            cfg, params, batches, args.max_seq,
+            OperationTracker(devices.local_device()), args.arch)
 
     if args.sweep:
         t0 = time.perf_counter()

@@ -17,8 +17,10 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.configs import ARCHS, get_config
 from repro.core import OperationTracker, cost as cost_mod, default_predictor
+from repro.core import devices
 from repro.models.config import smoke_config
 from repro.train.optim import adamw
 from repro.train.train_step import make_train_step
@@ -41,6 +43,7 @@ def main():
                          "(e.g. tpu-v5e,tpu-v5p,trainium2)")
     ap.add_argument("--predict-only", action="store_true")
     args = ap.parse_args()
+    runtime.use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -58,13 +61,14 @@ def main():
         batch = jax.tree.map(jax.numpy.asarray,
                              SyntheticTokens(cfg, args.batch,
                                              args.seq).batch_at(0))
-        tracker = OperationTracker(origin_device="cpu-host")
+        origin = devices.local_device()
+        tracker = OperationTracker(origin_device=origin)
         trace = tracker.track(step_fn, state, batch, label=args.arch)
         candidates = args.predict_on.split(",")
         ranking = cost_mod.rank_devices(trace, args.batch, candidates,
                                         predictor=default_predictor())
         print(f"\nPredicted training performance for {cfg.name} "
-              f"(batch={args.batch}, seq={args.seq}), traced on cpu-host:")
+              f"(batch={args.batch}, seq={args.seq}), traced on {origin}:")
         print(cost_mod.format_ranking(ranking))
         if args.predict_only:
             return

@@ -655,6 +655,7 @@ def _sse_event(event: str, payload: Dict) -> bytes:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
+    from repro import runtime
     from repro.serve.http import build_service, log_engine_caches
 
     ap = argparse.ArgumentParser(
@@ -683,6 +684,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "readiness, refreshed every "
                          "REPRO_SNAPSHOT_INTERVAL_S, finalized on drain")
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
 
     fleet = args.fleet.split(",") if args.fleet else None
     service = build_service(cache=args.cache, cache_size=args.cache_size,

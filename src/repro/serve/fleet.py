@@ -160,6 +160,26 @@ class FleetPlanner:
                 "wave_factor_cache": batched.WAVE_FACTOR_CACHE.stats(),
                 "scorer_dispatches": batched.SCORER_DISPATCHES.snapshot()}
 
+    def scorer_device(self) -> Optional[Dict]:
+        """Where the trained MLPs score, or None when there are none:
+        without MLPs the engine is NumPy on the host.
+
+        ``id``/``chip`` name the device holding the MLP weights, as this
+        process numbers it and as its host does; ``scored_on`` lists the
+        host chips the fused scorer's outputs actually came from (empty
+        until a sweep has run it)."""
+        from repro import runtime
+        mlps = getattr(self.predictor, "mlps", None)
+        if not mlps:
+            return None
+        (dev,) = next(iter(mlps.values())).params[0][0].devices()
+        built = getattr(self.predictor, "built_scorer", None)
+        scorer = built() if built else None
+        ran_on = scorer.ran_on.copy() if scorer else ()
+        return {"id": dev.id, "chip": runtime.host_chip(dev),
+                "platform": dev.platform, "kind": dev.device_kind,
+                "scored_on": sorted(runtime.host_chip(d) for d in ran_on)}
+
     # -- fleet -------------------------------------------------------------
     @property
     def fleet(self) -> List[str]:

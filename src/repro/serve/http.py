@@ -55,6 +55,7 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+from repro import runtime
 from repro.core.batched import env_float
 from repro.serve import faults
 from repro.serve.admission import AdmissionError
@@ -435,6 +436,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "readiness, refreshed every "
                          "REPRO_SNAPSHOT_INTERVAL_S, finalized on drain")
     args = ap.parse_args(argv)
+    runtime.use_compile_cache()
 
     fleet = args.fleet.split(",") if args.fleet else None
     service = build_service(cache=args.cache, cache_size=args.cache_size,

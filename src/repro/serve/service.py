@@ -1037,6 +1037,7 @@ class PredictionService:
                 "response_cache": self.response_cache_stats(),
                 "engine_caches": self.planner.engine_cache_stats(),
                 "fleet": self.planner.fleet,
+                "device": self.planner.scorer_device(),
                 "draining": self._draining,
                 "integrity": integrity.COUNTERS.stats(),
                 "quarantine": self.quarantine_stats(),

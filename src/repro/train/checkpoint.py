@@ -31,7 +31,9 @@ def _flatten(tree: Any):
     for path, leaf in flat:
         key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k)))
                         for k in path)
-        out[key] = np.asarray(leaf)
+        # a copy, not a view: the trainer donates the live state to the
+        # next step while the writer thread still reads this snapshot
+        out[key] = np.array(leaf, copy=True)
     return out, treedef
 
 

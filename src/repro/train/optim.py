@@ -2,6 +2,11 @@
 
 SGD (the paper uses it for the vision models), Adam (the rest), AdamW for
 the LM-family training runs.
+
+Optimizer state is float32 whatever the parameter dtype: the update does
+its math in float32, so bfloat16 moments would come back float32 and the
+train state would change type (and the jitted step recompile) after the
+first step.
 """
 
 from __future__ import annotations
@@ -20,10 +25,15 @@ class Optimizer:
     # update(grads, opt_state, params, step) -> (new_params, new_opt_state)
 
 
+def _zeros_f32(params):
+    return jax.tree.map(lambda p: jnp.zeros(jnp.shape(p), jnp.float32),
+                        params)
+
+
 def sgd(lr: float = 1e-2, momentum: float = 0.0) -> Optimizer:
     def init(params):
         if momentum:
-            return jax.tree.map(jnp.zeros_like, params)
+            return _zeros_f32(params)
         return ()
 
     def update(grads, state, params, step):
@@ -33,7 +43,9 @@ def sgd(lr: float = 1e-2, momentum: float = 0.0) -> Optimizer:
             upd = state
         else:
             upd = grads
-        new_params = jax.tree.map(lambda p, u: p - lr * u, params, upd)
+        new_params = jax.tree.map(
+            lambda p, u: (p.astype(jnp.float32) - lr * u).astype(p.dtype),
+            params, upd)
         return new_params, state
 
     return Optimizer(init, update)
@@ -41,8 +53,7 @@ def sgd(lr: float = 1e-2, momentum: float = 0.0) -> Optimizer:
 
 def _adam_core(lr, b1, b2, eps, wd):
     def init(params):
-        return {"m": jax.tree.map(jnp.zeros_like, params),
-                "v": jax.tree.map(jnp.zeros_like, params)}
+        return {"m": _zeros_f32(params), "v": _zeros_f32(params)}
 
     def update(grads, state, params, step):
         t = step.astype(jnp.float32) + 1.0

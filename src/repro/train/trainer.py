@@ -31,7 +31,8 @@ from repro.train.train_step import TrainState, init_state, make_train_step
 
 @dataclasses.dataclass
 class TrainerConfig:
-    checkpoint_dir: str = "/tmp/repro_ckpt"
+    #: ``None`` turns checkpointing (and restore-on-start) off
+    checkpoint_dir: Optional[str] = "/tmp/repro_ckpt"
     checkpoint_every: int = 50
     async_checkpoint: bool = True
     log_every: int = 10
@@ -50,17 +51,22 @@ class Trainer:
         self.tcfg = tcfg or TrainerConfig()
         self.optimizer = optimizer or adamw()
         self.data = SyntheticTokens(cfg, batch, seq, seed=seed)
+        # the state is donated: the step updates it in place instead of
+        # holding two copies of params and moments at once
         self.train_step = train_step or jax.jit(
-            make_train_step(cfg, self.optimizer))
+            make_train_step(cfg, self.optimizer), donate_argnums=0)
         self.state = init_state(cfg, jax.random.PRNGKey(seed),
                                 self.optimizer)
         self.failure_injector = failure_injector
         self.step_times: list = []
+        self.losses: list = []
         self.straggler_steps: list = []
         self._ckpt_thread = None
 
     # -- fault tolerance ----------------------------------------------------
     def restore_if_available(self, shardings: Any = None) -> int:
+        if self.tcfg.checkpoint_dir is None:
+            return 0
         step = checkpoint.latest_step(self.tcfg.checkpoint_dir)
         if step is None:
             return 0
@@ -69,6 +75,8 @@ class Trainer:
         return int(np.asarray(self.state.step))
 
     def _maybe_checkpoint(self, step: int, force: bool = False):
+        if self.tcfg.checkpoint_dir is None:
+            return
         if force or (step > 0 and step % self.tcfg.checkpoint_every == 0):
             if self._ckpt_thread is not None:
                 self._ckpt_thread.join()  # one in flight at a time
@@ -105,6 +113,7 @@ class Trainer:
                 ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
             loss = float(np.asarray(metrics["loss"]))
             losses.append(loss)
+            self.losses.append(loss)
             if step % self.tcfg.log_every == 0:
                 log(f"[trainer] step {step} loss {loss:.4f} "
                     f"{dt * 1e3:.1f}ms")

@@ -13,7 +13,8 @@ Pins the PR-9 robustness contracts:
   Retry-After, ``/healthz`` flips so routers mark the worker down, and
   a SIGTERMed worker process exits 0 after printing its accounting;
 * worker supervision — a killed worker restarts (same port pin) and a
-  worker that dies on arrival backs off instead of fork-bombing;
+  worker that dies on arrival backs off instead of fork-bombing, and a
+  launch interrupted or failed mid-start leaves no worker running;
 * router probes — HTTP 5xx on ``/healthz`` is "unhealthy" (alive but
   refusing), a dead transport is "down"; both leave the ring;
 * the netcache breaker's half-open ping probe closing the circuit once
@@ -397,6 +398,47 @@ def test_supervisor_restarts_killed_worker():
     finally:
         sup.drain(timeout=5.0)
     assert sup.procs[0].poll() is not None  # drain really stopped it
+
+
+def test_supervisor_interrupted_spawn_leaves_no_worker(tmp_path):
+    """SIGTERM while a worker starts (the launcher turns it into
+    KeyboardInterrupt) kills that worker: none outlives the launcher."""
+    from repro.launch.serve import WorkerSupervisor
+
+    pid_file = tmp_path / "pid"
+    cmd = [sys.executable, "-u", "-c",
+           "import os, sys, time; "
+           "open(sys.argv[1], 'w').write(str(os.getpid())); "
+           "time.sleep(600)", str(pid_file)]
+
+    def _interrupt(signum, frame):
+        raise KeyboardInterrupt
+
+    old = signal.signal(signal.SIGALRM, _interrupt)
+    signal.setitimer(signal.ITIMER_REAL, 1.0)
+    try:
+        with pytest.raises(KeyboardInterrupt):
+            WorkerSupervisor().spawn(cmd)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+    with pytest.raises(ProcessLookupError):
+        os.kill(int(pid_file.read_text()), 0)
+
+
+def test_supervisor_spawn_all_is_all_or_none():
+    """A worker that exits before binding stops the launch, and the
+    workers already up are drained with it."""
+    from repro.launch.serve import WorkerSupervisor
+
+    sup = WorkerSupervisor(poll_s=0.05)
+    up = [sys.executable, "-u", "-c",
+          "print('serving on fake://up'); import time; time.sleep(600)"]
+    dies = [sys.executable, "-c", "pass"]
+    with pytest.raises(SystemExit, match="before binding"):
+        sup.spawn_all([up, dies], [sup.env, sup.env])
+    assert len(sup.procs) == 1
+    assert sup.procs[0].poll() is not None
 
 
 def test_supervisor_backoff_on_crash_looping_worker():

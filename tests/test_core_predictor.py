@@ -131,6 +131,24 @@ def test_tracker_wallclock_measurement():
     tr = OperationTracker("cpu-host", measure="wallclock").track(
         _toy_step, jnp.zeros((64, 64)), jnp.zeros((16, 64)))
     assert tr.run_time_ms > 0
+    assert 0 < tr.coverage <= 1
+
+
+def test_wallclock_refuses_a_foreign_origin():
+    """Times taken on this process's device (the CPU here) must never be
+    labelled as another device's."""
+    with pytest.raises(ValueError, match="not the device this process"):
+        OperationTracker("tpu-v5e", measure="wallclock").track(
+            _toy_step, jnp.zeros((64, 64)), jnp.zeros((16, 64)))
+
+
+def test_device_kind_table():
+    assert devices.name_for_kind("TPU v5 lite") == "tpu-v5e"
+    assert devices.local_device() == "cpu-host"
+    for name in devices.DEVICE_KINDS.values():
+        devices.get(name)               # every mapped name is registered
+    with pytest.raises(KeyError, match="no registry device"):
+        devices.name_for_kind("TPU v99")
 
 
 # ---------------------------------------------------------------------------

@@ -43,14 +43,21 @@ def test_fuzz_from_json_decodes_or_rejects_cleanly(doc):
     assert back.fingerprint() == trace.fingerprint()
 
 
+@pytest.fixture(scope="module")
+def valid_doc():
+    """A valid trace document, built once — outside the examples that
+    hypothesis times."""
+    return json.dumps(OperationTracker("T4").track(
+        lambda w, x: jnp.sum(jnp.tanh(x @ w)), jnp.zeros((12, 24)),
+        jnp.zeros((8, 12)), label="fuzz").to_dict())
+
+
 @given(field=st.sampled_from(["origin_device", "label", "ops"]),
        value=_json_values)
-def test_fuzz_mutated_trace_documents(field, value, _valid=[]):
+def test_fuzz_mutated_trace_documents(valid_doc, field, value):
     """Mutating one top-level field of a VALID document keeps the same
     contract — the decoder validates fields, not just overall shape."""
-    if not _valid:      # build the costly valid doc once per process
-        _valid.append(OperationTracker("T4").track(lambda w, x: jnp.sum(jnp.tanh(x @ w)), jnp.zeros((12, 24)), jnp.zeros((8, 12)), label="fuzz").to_dict())
-    doc = json.loads(json.dumps(_valid[0]))
+    doc = json.loads(valid_doc)
     doc[field] = value
     try:
         trace = TrackedTrace.from_dict(doc)
