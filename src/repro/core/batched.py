@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import dataset as dataset_mod
 from repro.core import devices, wave_scaling
 from repro.core.devices import DeviceArrays, DeviceSpec
@@ -1291,7 +1292,8 @@ def predict_sweep(traces: Union[RaggedTraceArrays, Sequence[TrackedTrace]],
                 else:
                     feats_by_kind[kind] = mlp_features_grid(ragged, idx, da)
             if feats_by_kind:
-                scored = fused.score_ms(feats_by_kind)
+                with telemetry.span("engine.score"):
+                    scored = fused.score_ms(feats_by_kind)
                 for kind, idx in idx_by_kind.items():
                     out[idx] = scored[kind].reshape(len(idx), da.n)
         finally:
@@ -1479,7 +1481,8 @@ def _predict_sweep_masked(ragged: RaggedTraceArrays, da: DeviceArrays,
                 pair_features(buf[offset:offset + len(r)], idx, r, c)
                 kind_rows[offset:offset + len(r)] = fused.kinds.index(kind)
                 offset += len(r)
-            scored = fused.score_rows_ms(buf[:total], kind_rows)
+            with telemetry.span("engine.score"):
+                scored = fused.score_rows_ms(buf[:total], kind_rows)
         finally:
             if feature_buffers:
                 _FEATURE_BUFFERS.release(buf)

@@ -416,9 +416,11 @@ def serve_http(args) -> None:
         return
 
     if args.workers == 1:
+        from repro import telemetry
         from repro.serve.http import install_drain_handlers, \
             log_engine_caches
 
+        telemetry.install_gc_hook()
         service = build_service(cache=cache, coalesce_ms=args.coalesce_ms,
                                 mlps=args.fleet_mlps)
         snap_path = _worker_snapshot(args, 0)

@@ -48,6 +48,8 @@ multi-worker launcher and the tests).
 from __future__ import annotations
 
 import argparse
+import contextlib
+import itertools
 import json
 import signal
 import threading
@@ -55,7 +57,7 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
-from repro import runtime
+from repro import runtime, telemetry
 from repro.core.batched import env_float
 from repro.serve import faults
 from repro.serve.admission import AdmissionError
@@ -66,6 +68,9 @@ __all__ = ["PredictionServer", "PredictionClient", "main",
            "install_drain_handlers"]
 
 _MAX_BODY = 64 * 1024 * 1024    # refuse absurd payloads, not big sweeps
+#: ids of POST requests, the ``req`` of their spans in a profiler trace
+_REQUEST_IDS = itertools.count(1)
+_UNTIMED = contextlib.nullcontext()
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -78,14 +83,16 @@ class _Handler(BaseHTTPRequestHandler):
         # service spells non-finite numbers as strings on the wire); a
         # stray inf/nan raises here and surfaces as a 400/500, never as
         # an unparsable 200
-        body = json.dumps(payload, allow_nan=False).encode()
-        self.send_response(code)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        for key, value in extra:
-            self.send_header(key, value)
-        self.end_headers()
-        self.wfile.write(body)
+        with (telemetry.span("http.reply") if self.command == "POST"
+              else _UNTIMED):
+            body = json.dumps(payload, allow_nan=False).encode()
+            self.send_response(code)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            for key, value in extra:
+                self.send_header(key, value)
+            self.end_headers()
+            self.wfile.write(body)
 
     def _read_json(self) -> Optional[str]:
         """The request body as its RAW string (UTF-8 checked only).
@@ -140,18 +147,27 @@ class _Handler(BaseHTTPRequestHandler):
         return float(raw)
 
     def do_POST(self) -> None:  # noqa: N802
-        service: PredictionService = self.server.service
+        with telemetry.context(req=next(_REQUEST_IDS)):
+            with telemetry.span("http.read"):
+                payload = self._accept_post()
+            if payload is not None:
+                self._answer_post(payload)
+
+    def _accept_post(self) -> Optional[str]:
+        """The body of a POST this worker takes, or None once answered
+        (unknown path, draining, unreadable body)."""
         if self.path not in ("/rank", "/sweep", "/optimize"):
             self._reply(404, {"error": f"unknown path {self.path!r}"})
-            return
-        if service.draining:
+            return None
+        if self.server.service.draining:
             # stop accepting: in-flight work flushes, new work sheds
             self._reply(503, {"error": "draining", "retry_after_s": 1.0},
                         extra=[("Retry-After", "1")])
-            return
-        payload = self._read_json()
-        if payload is None:
-            return
+            return None
+        return self._read_json()
+
+    def _answer_post(self, payload: str) -> None:
+        service: PredictionService = self.server.service
         try:
             deadline_ms = self._deadline_ms()
             if self.path == "/rank":
@@ -437,6 +453,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          "REPRO_SNAPSHOT_INTERVAL_S, finalized on drain")
     args = ap.parse_args(argv)
     runtime.use_compile_cache()
+    telemetry.install_gc_hook()
 
     fleet = args.fleet.split(",") if args.fleet else None
     service = build_service(cache=args.cache, cache_size=args.cache_size,

@@ -85,6 +85,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, \
     Union
 
+from repro import telemetry
 from repro.core import integrity
 from repro.core.batched import env_float, env_int
 from repro.core.trace import TrackedTrace
@@ -174,6 +175,12 @@ class PendingQuery:
     result: Any = None
     error: Optional[BaseException] = None
     on_done: Optional[Callable[["PendingQuery"], None]] = None
+    #: ``time.perf_counter_ns()`` when the query joined the queue
+    enqueued_ns: int = 0
+    #: the submitting thread's span metadata (``telemetry.context``);
+    #: the leader adds the batch that took the query
+    meta: Optional[Dict] = dataclasses.field(
+        default_factory=telemetry.current, repr=False)
     _finalize_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False)
     _finalized: bool = dataclasses.field(default=False, repr=False)
@@ -430,8 +437,10 @@ class PredictionService:
              dests: Optional[Sequence[str]] = None,
              deadline: Optional[float] = None) -> List[FleetChoice]:
         """Coalesced equivalent of ``FleetPlanner.rank`` (same answer)."""
-        return self._submit(self.submit_rank(trace, batch_size, by, dests,
-                                             deadline=deadline))
+        req = self.submit_rank(trace, batch_size, by, dests,
+                               deadline=deadline)
+        with telemetry.wait("rank.wait"):
+            return self._submit(req)
 
     def sweep(self, traces: Sequence[TrackedTrace],
               dests: Optional[Sequence[str]] = None,
@@ -676,16 +685,19 @@ class PredictionService:
         429/503 + Retry-After) and
         :class:`~repro.serve.admission.DeadlineExceeded` (504) when the
         deadline budget is blown at admission or delivery."""
-        rkey = self.response_key("rank", payload)
-        cached = self.response_lookup(rkey)
+        with telemetry.span("rank.lookup"):
+            rkey = self.response_key("rank", payload)
+            cached = self.response_lookup(rkey)
         if cached is not None:
             return cached
-        p = json.loads(payload) if isinstance(payload, str) else payload
-        trace, batch_size, by, dests = self.decode_rank(p)
-        self.check_quarantine([trace])
-        deadline = self.resolve_deadline(p, deadline_ms)
-        ticket = self.admit_request("rank", [trace], dests,
-                                    deadline=deadline)
+        with telemetry.span("rank.decode"):
+            p = json.loads(payload) if isinstance(payload, str) else payload
+            trace, batch_size, by, dests = self.decode_rank(p)
+        with telemetry.span("rank.admit"):
+            self.check_quarantine([trace])
+            deadline = self.resolve_deadline(p, deadline_ms)
+            ticket = self.admit_request("rank", [trace], dests,
+                                        deadline=deadline)
         try:
             choices = self.rank(trace, batch_size, by=by, dests=dests,
                                 deadline=deadline)
@@ -694,8 +706,9 @@ class PredictionService:
             raise
         finally:
             self.admission.release(ticket)
-        out = self.encode_rank(trace, choices)
-        self.response_store(rkey, out)
+        with telemetry.span("rank.encode"):
+            out = self.encode_rank(trace, choices)
+            self.response_store(rkey, out)
         return out
 
     @staticmethod
@@ -1044,7 +1057,8 @@ class PredictionService:
                 "snapshot": (self._snapshot.stats()
                              if self._snapshot is not None
                              else snapshot_mod.empty_stats()),
-                "faults": faults.stats()}
+                "faults": faults.stats(),
+                "spans": telemetry.stats()}
 
     # -- coalescing core ----------------------------------------------------
     def _enqueue(self, req: PendingQuery) -> None:
@@ -1053,6 +1067,7 @@ class PredictionService:
         The leader runs on its own daemon thread so non-blocking
         submitters return immediately; a blocking caller simply waits on
         the handle like everyone else."""
+        req.enqueued_ns = time.perf_counter_ns()
         with self._cond:
             self._pending.append(req)
             self._requests[req.kind] += 1
@@ -1103,6 +1118,7 @@ class PredictionService:
             self._leader_active = False
             self._executing += 1
             self._batches += 1
+            batch_id = self._batches
             self._max_batch = max(self._max_batch, len(batch))
             if len(batch) > 1:
                 self._coalesced_requests += len(batch)
@@ -1110,8 +1126,14 @@ class PredictionService:
             # (alpha 0.3 — a handful of batches to adapt, so one odd
             # batch cannot whip the window around)
             self._batch_ewma += 0.3 * (len(batch) - self._batch_ewma)
+        for q in batch:
+            if q.kind == "rank":
+                telemetry.wait("rank.queue", since_ns=q.enqueued_ns)
+            if q.meta is not None:
+                q.meta["batch"] = batch_id
         try:
-            self._execute(batch)
+            with telemetry.context(batch=batch_id):
+                self._execute(batch)
         finally:
             with self._cond:
                 self._executing -= 1
@@ -1357,11 +1379,10 @@ class PredictionService:
                                                  or req.deadline < scope):
                     scope = req.deadline
             faults.inject("engine.pass")
-            t0 = time.perf_counter()
-            with deadline_scope(scope):
+            with telemetry.span("engine.pass") as engine_pass, \
+                    deadline_scope(scope):
                 rows = self.planner.sweep([uniq[fp] for fp in order],
                                           dests=union)
-            dt = time.perf_counter() - t0
             # credit the sample with the op-cells actually COMPUTED, not
             # the full rectangle: with cell-level cache fills a warm pass
             # computes almost nothing, and pricing it as the rectangle
@@ -1381,7 +1402,7 @@ class PredictionService:
                           * len(union))
             cells = (rect_cells * cold_pairs // total_pairs
                      if total_pairs else 0)
-            self._record_pass(cells, rect_cells, dt)
+            self._record_pass(cells, rect_cells, engine_pass.seconds)
             by_fp = dict(zip(order, rows))
             sliced = 0
             for req, dlist in resolved:
