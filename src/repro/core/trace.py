@@ -28,6 +28,7 @@ import jax
 import numpy as np
 from jax.extend import core as jcore
 
+from repro import telemetry
 from repro.core import costmodel, devices
 from repro.core.costmodel import OpCost
 
@@ -111,7 +112,7 @@ class Op:
             raise TraceValidationError(
                 f"op.params must be an object, "
                 f"got {type(raw_params).__name__}")
-        for key in _FEATURE_PARAM_KEYS.get(kind, ()):
+        for key, _ in _FEATURE_LAYOUT.get(kind, ()):
             if key in raw_params:
                 _v_num(raw_params[key], f"op.params.{key}")
         return Op(
@@ -141,23 +142,13 @@ class Op:
         an addition over the paper: in JAX a "kind" covers heterogeneous
         jaxpr patterns (e.g. ``recurrent`` spans LSTM, GRU and SSD scans),
         so the dimensions alone do not determine the work performed."""
-        p = self.params
-        if self.kind == "conv2d":
-            f = [p.get("batch", 1), p.get("in_ch", 1), p.get("out_ch", 1),
-                 p.get("kernel", 1), p.get("padding", 0), p.get("stride", 1),
-                 p.get("image", 1)]
-        elif self.kind == "linear":
-            f = [p.get("batch", 1), p.get("in_f", 1), p.get("out_f", 1),
-                 p.get("bias", 0), 0, 0, 0]
-        elif self.kind == "bmm":
-            f = [p.get("b", 1), p.get("m", 1), p.get("n", 1), p.get("k", 1),
-                 0, 0, 0]
-        elif self.kind == "recurrent":
-            f = [p.get("batch", 1), p.get("in_f", 1), p.get("hidden", 1),
-                 p.get("seq", 1), p.get("layers", 1), p.get("bidir", 0),
-                 p.get("bias", 0)]
-        else:
+        layout = _FEATURE_LAYOUT.get(self.kind)
+        if layout is None:
             f = [self.cost.intensity, 0, 0, 0, 0, 0, 0]
+        else:
+            p = self.params
+            f = ([p.get(key, default) for key, default in layout]
+                 + [0] * (7 - len(layout)))
         f = f + [self.cost.flops, self.cost.bytes_accessed]
         return [float(x) for x in f]
 
@@ -187,16 +178,17 @@ class TraceValidationError(ValueError):
     before: the bitwise round-trip guarantees below are unchanged."""
 
 
-#: params keys ``Op.feature_vector`` feeds through ``float()`` per
-#: kernel-varying kind — these must be numeric when present, or MLP
-#: scoring would crash mid-engine-pass long after admission
-_FEATURE_PARAM_KEYS = {
-    "conv2d": ("batch", "in_ch", "out_ch", "kernel", "padding", "stride",
-               "image"),
-    "linear": ("batch", "in_f", "out_f", "bias"),
-    "bmm": ("b", "m", "n", "k"),
-    "recurrent": ("batch", "in_f", "hidden", "seq", "layers", "bidir",
-                  "bias"),
+#: per kernel-varying kind, the params ``Op.feature_vector`` reads, in
+#: order, each with the value it takes when absent (padded to 7 with 0);
+#: a present one must be numeric, or MLP scoring would crash
+#: mid-engine-pass long after admission
+_FEATURE_LAYOUT = {
+    "conv2d": (("batch", 1), ("in_ch", 1), ("out_ch", 1), ("kernel", 1),
+               ("padding", 0), ("stride", 1), ("image", 1)),
+    "linear": (("batch", 1), ("in_f", 1), ("out_f", 1), ("bias", 0)),
+    "bmm": (("b", 1), ("m", 1), ("n", 1), ("k", 1)),
+    "recurrent": (("batch", 1), ("in_f", 1), ("hidden", 1), ("seq", 1),
+                  ("layers", 1), ("bidir", 0), ("bias", 0)),
 }
 
 _MAX_OPS_DEFAULT = 500_000
@@ -423,10 +415,32 @@ class TraceArrays:
         return self._fingerprint
 
 
+class _LazyOps:
+    """``TrackedTrace.ops``: a trace decoded column by column keeps its
+    document's op list and builds its ``Op`` objects on the first read
+    (span ``trace.ops_built``); a trace built from an ``Op`` list holds
+    it as given."""
+
+    def __get__(self, obj, owner=None) -> List[Op]:
+        if obj is None:         # class access: the field has no default
+            raise AttributeError("ops")
+        ops = obj.__dict__["_ops"]
+        if ops is None:
+            with telemetry.span("trace.ops_built"):
+                ops = _ops_from_doc(obj._doc)
+            obj.__dict__["_ops"] = ops
+            obj._doc = None
+        return ops
+
+    def __set__(self, obj, ops: List[Op]) -> None:
+        obj.__dict__["_ops"] = ops
+        obj.__dict__["_doc"] = None     # these ops replace the document's
+
+
 @dataclasses.dataclass
 class TrackedTrace:
     """The result of tracking one training/serving iteration."""
-    ops: List[Op]
+    ops: List[Op] = _LazyOps()
     origin_device: str
     label: str = "iteration"
     #: fraction of iteration time that :meth:`measure` timed for real on
@@ -438,16 +452,28 @@ class TrackedTrace:
         default=None, repr=False, compare=False)
     _fp: Optional[str] = dataclasses.field(
         default=None, repr=False, compare=False)
+    #: the op documents ``ops`` is built from on first read (column-wise
+    #: decode only; None once built)
+    _doc: Optional[List[Dict[str, Any]]] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    #: ``run_time_ms`` as the column-wise decode summed it (None where an
+    #: op is unmeasured), read while ``_doc`` stands for the ops
+    _run_ms: Optional[float] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     # ---- aggregate views -------------------------------------------------
     @property
     def run_time_ms(self) -> float:
-        times = [(op.predicted_ms if op.predicted_ms is not None
-                  else op.measured_ms) for op in self.ops]
-        if any(t is None for t in times):
+        if self._doc is not None:       # ops not built yet: the decode's
+            total = self._run_ms
+        else:
+            times = [(op.predicted_ms if op.predicted_ms is not None
+                      else op.measured_ms) for op in self.ops]
+            total = None if any(t is None for t in times) else float(
+                sum(t * op.multiplicity for t, op in zip(times, self.ops)))
+        if total is None:
             raise ValueError("trace has unmeasured ops; call measure() first")
-        return float(sum(t * op.multiplicity
-                         for t, op in zip(times, self.ops)))
+        return total
 
     @property
     def total_cost(self) -> OpCost:
@@ -535,28 +561,19 @@ class TrackedTrace:
         ``REPRO_TRACE_MAX_OPS``.  The origin device is deliberately NOT
         checked against the registry here — an unknown origin is a
         semantic failure the engine reports (and the quarantine layer
-        tracks), not a malformed document."""
-        if not isinstance(d, dict):
-            raise TraceValidationError(
-                f"trace document must be an object, "
-                f"got {type(d).__name__}")
-        try:
-            ops_doc, origin = d["ops"], d["origin_device"]
-        except KeyError as e:
-            raise TraceValidationError(
-                f"trace document missing field {e}") from None
-        if not isinstance(ops_doc, list):
-            raise TraceValidationError(
-                f"trace.ops must be a list, got {type(ops_doc).__name__}")
-        max_ops = _trace_max_ops()
-        if len(ops_doc) > max_ops:
-            raise TraceValidationError(
-                f"trace has {len(ops_doc)} ops, over the wire-entry cap "
-                f"of {max_ops} (REPRO_TRACE_MAX_OPS)")
-        origin = _v_str(origin, "trace.origin_device")
-        label = _v_str(d.get("label", "iteration"), "trace.label")
-        return TrackedTrace(ops=[Op.from_dict(o) for o in ops_doc],
-                            origin_device=origin, label=label)
+        tracks), not a malformed document.
+
+        A sound document decodes column by column straight into the
+        trace's arrays and fingerprint; its ``ops`` are built on first
+        read, and the trace keeps ``d["ops"]`` until then, so the caller
+        leaves it unchanged.  Anything else (an error, NumPy scalars,
+        integers of 2**53 or more) takes the per-op decode (span
+        ``trace.decode_slow``); both give the same trace."""
+        trace = _decode_columns(d)
+        if trace is None:
+            with telemetry.span("trace.decode_slow"):
+                trace = _decode_per_op(d)
+        return trace
 
     def to_json(self) -> str:
         import json
@@ -597,6 +614,185 @@ class TrackedTrace:
         from repro.core import predictor as predictor_mod
         pred = predictor or predictor_mod.default_predictor()
         return pred.predict_trace(self, dest)
+
+
+def _decode_per_op(d: Any) -> TrackedTrace:
+    """Decode a trace document op by op through :meth:`Op.from_dict`."""
+    if not isinstance(d, dict):
+        raise TraceValidationError(
+            f"trace document must be an object, "
+            f"got {type(d).__name__}")
+    try:
+        ops_doc, origin = d["ops"], d["origin_device"]
+    except KeyError as e:
+        raise TraceValidationError(
+            f"trace document missing field {e}") from None
+    if not isinstance(ops_doc, list):
+        raise TraceValidationError(
+            f"trace.ops must be a list, got {type(ops_doc).__name__}")
+    max_ops = _trace_max_ops()
+    if len(ops_doc) > max_ops:
+        raise TraceValidationError(
+            f"trace has {len(ops_doc)} ops, over the wire-entry cap "
+            f"of {max_ops} (REPRO_TRACE_MAX_OPS)")
+    origin = _v_str(origin, "trace.origin_device")
+    label = _v_str(d.get("label", "iteration"), "trace.label")
+    return TrackedTrace(ops=[Op.from_dict(o) for o in ops_doc],
+                        origin_device=origin, label=label)
+
+
+#: ints below this read the same as ints and as float64 (an int at or
+#: above it may read as another, after the float64 rounds it)
+_EXACT_INT = 2 ** 53
+_NUMBER = {float, int}
+_NUMBER_OR_NONE = {float, int, type(None)}
+
+
+def _of_types(col: List[Any], types) -> bool:
+    return set(map(type, col)) <= types
+
+
+def _num_column(col: List[Any], allow_none: bool = False,
+                integral: bool = False) -> Optional[np.ndarray]:
+    """A column of ``_v_num`` fields as float64 (NaN where None), or None
+    where the per-op check could reject it or read it otherwise: a type
+    other than ``float``, ``int`` (and None where ``allow_none``), a NaN,
+    infinite or negative value, a fraction where ``integral``, an
+    ``int`` of 2**53 or more."""
+    types = set(map(type, col))
+    if not types <= (_NUMBER_OR_NONE if allow_none else _NUMBER):
+        return None
+    arr = np.array(col, np.float64)
+    vals = arr
+    if type(None) in types:
+        missing = np.isnan(arr)
+        if np.count_nonzero(missing) != col.count(None):
+            return None         # a NaN in the document, not a None
+        arr[missing] = np.nan
+        vals = arr[~missing]
+    if not ((vals >= 0) & (vals < np.inf)).all():
+        return None
+    if int in types and (vals >= _EXACT_INT).any():
+        return None
+    if integral:
+        if (vals != np.floor(vals)).any():
+            return None
+        arr += 0.0              # -0.0 reads as int 0 in the per-op decode
+    return arr
+
+
+def _shapes_column(col: List[Any]) -> bool:
+    """Whether every entry is a list of lists of ints in [0, 2**53)."""
+    if not _of_types(col, {list}):
+        return False
+    shapes = [s for v in col for s in v]
+    if not _of_types(shapes, {list}):
+        return False
+    dims = [x for s in shapes for x in s]
+    return _of_types(dims, {int}) and (
+        not dims or (min(dims) >= 0 and max(dims) < _EXACT_INT))
+
+
+def _decode_columns(d: Any) -> Optional[TrackedTrace]:
+    """A trace document decoded column by column into the trace's
+    arrays and fingerprint, with its ops left to build on demand; None
+    where anything in it is not what a sound JSON document holds, so
+    that the per-op decode reads it (and raises if it is malformed).
+
+    Each column is pulled out whole, type-checked once and checked with
+    NumPy to the per-op rules; the arithmetic is ``to_arrays``' and
+    ``Op.feature_vector``'s in float64, so the arrays are bitwise
+    theirs."""
+    if type(d) is not dict:
+        return None
+    ops_doc, origin = d.get("ops"), d.get("origin_device")
+    label = d.get("label", "iteration")
+    if (type(ops_doc) is not list or type(origin) is not str
+            or type(label) is not str or len(ops_doc) > _trace_max_ops()
+            or not _of_types(ops_doc, {dict})):
+        return None
+    try:
+        kind_col = [o["kind"] for o in ops_doc]
+        if not (_of_types(kind_col, {str})
+                and _of_types([o["name"] for o in ops_doc], {str})
+                and _of_types([o["dtype"] for o in ops_doc], {str})
+                and _shapes_column([o["in_shapes"] for o in ops_doc])
+                and _shapes_column([o["out_shapes"] for o in ops_doc])):
+            return None
+        costs = [o["cost"] for o in ops_doc]
+        params = [o["params"] for o in ops_doc]
+        if not (_of_types(costs, {dict}) and _of_types(params, {dict})):
+            return None
+        flops = _num_column([c["flops"] for c in costs])
+        read = _num_column([c["bytes_read"] for c in costs])
+        written = _num_column([c["bytes_written"] for c in costs])
+        mult = _num_column([o["multiplicity"] for o in ops_doc],
+                           integral=True)
+        measured = _num_column([o["measured_ms"] for o in ops_doc],
+                               allow_none=True)
+        predicted = _num_column([o["predicted_ms"] for o in ops_doc],
+                                allow_none=True)
+        if any(c is None for c in (flops, read, written, mult, measured,
+                                   predicted)):
+            return None
+        kinds = sorted(set(kind_col))
+        kind_index = {k: i for i, k in enumerate(kinds)}
+        kind_ids = np.fromiter(map(kind_index.__getitem__, kind_col),
+                               np.int32, len(kind_col))
+        varying = np.array([k in KERNEL_VARYING_KINDS for k in kinds],
+                           bool)[kind_ids]
+        bytes_accessed = read + written
+        intensity = flops / np.maximum(bytes_accessed, 1.0)
+        feats = np.zeros((len(ops_doc), 9), np.float64)
+        feats[:, 0] = intensity         # kernel-varying rows: below
+        feats[:, 7] = flops
+        feats[:, 8] = bytes_accessed
+        for kind, layout in _FEATURE_LAYOUT.items():
+            if kind not in kind_index:
+                continue
+            rows = np.flatnonzero(kind_ids == kind_index[kind])
+            kind_params = [params[i] for i in rows.tolist()]
+            for j, (key, default) in enumerate(layout):
+                col = _num_column([p.get(key, default)
+                                   for p in kind_params])
+                if col is None:
+                    return None
+                feats[rows, j] = col
+    except (KeyError, OverflowError):   # a missing field; an int past 2**63
+        return None
+    times = np.where(np.isnan(predicted), measured, predicted)
+    run_ms = None if np.isnan(times).any() else float(
+        sum((times * mult).tolist()))   # run_time_ms's sum, term by term
+    trace = TrackedTrace(
+        ops=None, origin_device=origin, label=label, _doc=ops_doc,
+        _run_ms=run_ms,
+        _arrays=TraceArrays(
+            flops=flops, bytes_accessed=bytes_accessed, intensity=intensity,
+            measured_ms=measured, multiplicity=mult, kernel_varying=varying,
+            kind_ids=kind_ids, kinds=kinds, op_features=feats))
+    trace.fingerprint()
+    return trace
+
+
+def _ops_from_doc(ops_doc: List[Dict[str, Any]]) -> List[Op]:
+    """The ops of a document :func:`_decode_columns` accepted, checked
+    no further: the ``Op`` objects ``Op.from_dict`` builds from it."""
+    ops = []
+    for o in ops_doc:
+        c, m, p = o["cost"], o["measured_ms"], o["predicted_ms"]
+        ops.append(Op(
+            name=o["name"], kind=o["kind"],
+            cost=OpCost(flops=float(c["flops"]),
+                        bytes_read=float(c["bytes_read"]),
+                        bytes_written=float(c["bytes_written"])),
+            multiplicity=int(o["multiplicity"]),
+            params=dict(o["params"]),
+            in_shapes=tuple(map(tuple, o["in_shapes"])),
+            out_shapes=tuple(map(tuple, o["out_shapes"])),
+            dtype=o["dtype"],
+            measured_ms=None if m is None else float(m),
+            predicted_ms=None if p is None else float(p)))
+    return ops
 
 
 class OperationTracker:

@@ -890,10 +890,10 @@ class PredictionService:
         next crash instead of buying a fresh run of N."""
         if self.quarantine_threshold <= 0:
             return
+        fps = [t.fingerprint() for t in traces]     # set by the decode
         now = time.monotonic()
         with self._quar_lock:
-            for t in traces:
-                fp = t.fingerprint()
+            for fp in fps:
                 entry = self._quarantined.get(fp)
                 if entry is None:
                     continue

@@ -48,13 +48,15 @@ NAMES = (
     "http.read",        # handler start to the POST body in memory
     "http.reply",       # a POST's answer: JSON encode and socket write
     "rank.lookup",      # response-cache key and probe
-    "rank.decode",      # json.loads and the trace document's decode
+    "rank.decode",      # json.loads, the trace's arrays and fingerprint
     "rank.admit",       # quarantine check, deadline and admission
     "rank.queue",       # wait: enqueued to taken by a leader
     "rank.wait",        # wait: the handler blocked on its query
     "rank.encode",      # the answer's wire document and response store
     "engine.pass",      # one union engine pass (planner.sweep)
     "engine.score",     # one scorer call: transfer, launch, readback
+    "trace.decode_slow",  # a trace document decoded op by op
+    "trace.ops_built",  # a column-decoded trace's ops built on first read
     "gc.pause",         # a collector pause (install_gc_hook)
 )
 GC_PAUSE = "gc.pause"
