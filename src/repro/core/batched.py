@@ -114,6 +114,30 @@ class _DispatchCounters:
 SCORER_DISPATCHES = _DispatchCounters()
 
 
+class _RowCounters:
+    """Process-wide rows of the fused scorer's launches: ``real`` feature
+    rows, and ``padded`` rows added to fill whole row blocks and the jit
+    bucket (``/stats`` ``scorer_rows``)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.real = 0
+        self.padded = 0
+
+    def add(self, real: int, launched: int) -> None:
+        with self._lock:
+            self.real += real
+            self.padded += launched - real
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return {"real": self.real, "padded": self.padded}
+
+
+#: rows the fused scorer launched, real and padding
+SCORER_ROWS = _RowCounters()
+
+
 class _WaveFactorCache:
     """Cross-stack LRU of t-independent wave-scaling factor grids.
 
@@ -1053,6 +1077,7 @@ class FusedMLPScorer:
                                    np.float32))
             kind_of_block.extend([0] * pad_blocks)
         SCORER_DISPATCHES.bump("fused")
+        SCORER_ROWS.add(sum(counts), len(kind_of_block) * bm)
         log_ms = self._host(kernel_ops.fused_mlp_score(
             jnp.asarray(np.concatenate(blocks)),
             jnp.asarray(np.asarray(kind_of_block, np.int32)),
@@ -1138,6 +1163,7 @@ class FusedMLPScorer:
             xs = np.zeros((len(self.kinds), bpad, self.hidden), np.float32)
             for ki, rows in enumerate(rows_by_kind):
                 xs[ki, :len(rows), :xn.shape[1]] = xn[rows]
+            SCORER_ROWS.add(m, xs.shape[0] * bpad)
             log_grid = self._host(kernel_ops.fused_mlp_score_stacked(
                 jnp.asarray(xs), self.weights, self.biases))
             log_ms = np.empty(m, np.float32)
@@ -1150,6 +1176,7 @@ class FusedMLPScorer:
             row_kinds = np.zeros(padded, np.int32)
             row_kinds[:m] = kind_ids
             xp[:m, :xn.shape[1]] = xn
+            SCORER_ROWS.add(m, padded)
             log_ms = self._host(kernel_ops.fused_mlp_score_rows(
                 jnp.asarray(xp), jnp.asarray(row_kinds), self.weights,
                 self.biases, block_m=bm, impl=impl))[:m]

@@ -148,9 +148,9 @@ def eqn_cost(eqn) -> Tuple[OpCost, Dict[str, Any]]:
         costs = [jaxpr_cost(b.jaxpr) for b in branches]
         worst = max(costs, key=lambda c: c.flops + c.bytes_accessed)
         return worst, {"branches": len(branches)}
-    if prim in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+    if prim in ("jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
                 "custom_vjp_call", "custom_vjp_call_jaxpr", "remat_call",
-                "remat", "checkpoint", "named_call", "custom_lin"):
+                "remat", "remat2", "checkpoint", "named_call", "custom_lin"):
         sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if sub is not None:
             inner_jaxpr = sub.jaxpr if hasattr(sub, "jaxpr") else sub

@@ -3,7 +3,7 @@
 The paper intercepts PyTorch operations by monkey-patching (Sec. 4.1).  In
 JAX the computation graph is *first class*: tracing a step function yields a
 jaxpr whose equations are exactly the operations that will run.  We walk the
-jaxpr (recursing through pjit/remat/cond, and through scan with
+jaxpr (recursing through jit/remat/cond, and through scan with
 multiplicity) and produce a :class:`TrackedTrace` — an ordered list of
 :class:`Op` records, each carrying its analytical cost (flops/bytes), its
 MLP feature vector, and its kernel-alike/kernel-varying classification.
@@ -17,6 +17,7 @@ Listing-1-compatible usage::
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import math
@@ -38,9 +39,17 @@ from repro.core.costmodel import OpCost
 # Mosaic/XLA retile them per generation.
 KERNEL_VARYING_KINDS = ("conv2d", "linear", "bmm", "recurrent")
 
-_HIGHER_ORDER = ("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
-                 "remat", "checkpoint", "named_call", "core_call",
-                 "custom_vjp_call_jaxpr", "custom_lin")
+#: call-like primitives whose sub-jaxpr is walked in place (JAX 0.9 names
+#: a nested ``jax.jit`` call ``jit`` and a ``jax.checkpoint`` ``remat2``)
+_HIGHER_ORDER = ("jit", "pjit", "closed_call", "custom_jvp_call",
+                 "custom_vjp_call", "remat", "remat2", "checkpoint",
+                 "named_call", "core_call", "custom_vjp_call_jaxpr",
+                 "custom_lin")
+#: ops that only relabel an array (layout, dtype, a slice of it): a
+#: matmul operand reached through them is still that array
+_RELABEL = ("reshape", "transpose", "convert_element_type", "squeeze",
+            "expand_dims", "broadcast_in_dim", "copy", "copy_p", "slice",
+            "dynamic_slice")
 
 
 @dataclasses.dataclass
@@ -227,7 +236,11 @@ def _v_num(v: Any, where: str, allow_none: bool = False,
     if isinstance(v, bool) or not isinstance(v, numbers.Real):
         raise TraceValidationError(
             f"{where}: expected a number, got {type(v).__name__}: {v!r}")
-    f = float(v)
+    try:
+        f = float(v)
+    except OverflowError:       # an integer past float64's range
+        raise TraceValidationError(
+            f"{where}: too large for a float") from None
     if not math.isfinite(f):
         raise TraceValidationError(f"{where}: must be finite, got {f!r}")
     if f < 0:
@@ -282,17 +295,58 @@ def _classify_conv(eqn) -> Dict[str, Any]:
     }
 
 
-def _scan_is_recurrent(jaxpr) -> bool:
-    """A scan whose body does a matmul is a recurrent (kernel-varying) op."""
+def _sub_jaxpr(eqn):
+    """The jaxpr a call-like equation runs, or None."""
+    sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+    if sub is None:
+        return None
+    return sub.jaxpr if hasattr(sub, "jaxpr") else sub
+
+
+def _has_matmul(jaxpr) -> bool:
     for eqn in jaxpr.eqns:
         if eqn.primitive.name in ("dot_general", "conv_general_dilated"):
             return True
-        sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-        if sub is not None:
-            inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-            if _scan_is_recurrent(inner):
+        sub = _sub_jaxpr(eqn)
+        if sub is not None and _has_matmul(sub):
+            return True
+    return False
+
+
+def _matmul_reads(jaxpr, sliced) -> bool:
+    """Whether a matmul of ``jaxpr`` (or of a call inside it) takes one of
+    the variables ``sliced`` as an operand, through relabelling ops."""
+    sliced = set(sliced)
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        ins = [v for v in eqn.invars if not isinstance(v, jcore.Literal)]
+        if prim in ("dot_general", "conv_general_dilated"):
+            if any(v in sliced for v in ins):
+                return True
+        elif prim in _RELABEL:
+            if ins and ins[0] in sliced:
+                sliced.update(eqn.outvars)
+        elif (sub := _sub_jaxpr(eqn)) is not None:    # a call, a scan
+            if len(sub.invars) == len(eqn.invars) and _matmul_reads(
+                    sub, [s for s, v in zip(sub.invars, eqn.invars)
+                          if not isinstance(v, jcore.Literal)
+                          and v in sliced]):
                 return True
     return False
+
+
+def _scan_by_trip_count(eqn) -> bool:
+    """Whether a scan is walked as ``length`` runs of its body rather than
+    kept as one ``recurrent`` op.  A scan whose matmuls take a per-step
+    slice of its ``xs`` (a layer stack's weights, a chunk's states, a
+    block of keys) is a loop over independent work; one whose matmuls
+    take only loop-invariant operands and the carry (an LSTM's cell) is
+    a recurrence, and so is priced as one."""
+    body = eqn.params["jaxpr"].jaxpr
+    if not _has_matmul(body):
+        return True
+    first_xs = eqn.params["num_consts"] + eqn.params["num_carry"]
+    return _matmul_reads(body, body.invars[first_xs:])
 
 
 def _recurrent_params(eqn) -> Dict[str, Any]:
@@ -313,10 +367,11 @@ def _walk(jaxpr, ops: List[Op], multiplicity: int) -> None:
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         if prim in _HIGHER_ORDER:
-            sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+            sub = _sub_jaxpr(eqn)
             if sub is not None:
-                _walk(sub.jaxpr if hasattr(sub, "jaxpr") else sub,
-                      ops, multiplicity)
+                with (telemetry.span("trace.jit_walked")
+                      if prim in ("jit", "pjit") else contextlib.nullcontext()):
+                    _walk(sub, ops, multiplicity)
             continue
         if prim == "cond":
             # Track the most expensive branch (paper: worst case per step).
@@ -329,38 +384,48 @@ def _walk(jaxpr, ops: List[Op], multiplicity: int) -> None:
             _walk(eqn.params["body_jaxpr"].jaxpr, ops, multiplicity)
             continue
         if prim == "scan":
-            body = eqn.params["jaxpr"].jaxpr
             length = int(eqn.params["length"])
-            if _scan_is_recurrent(body):
+            if _scan_by_trip_count(eqn):
+                with telemetry.span("trace.scan_walked"):
+                    _walk(eqn.params["jaxpr"].jaxpr, ops,
+                          multiplicity * length)
+                continue
+            with telemetry.span("trace.scan_recurrent"):
                 cost, _ = costmodel.eqn_cost(eqn)
-                p = _recurrent_params(eqn)
                 ops.append(Op(
                     name="scan", kind="recurrent", cost=cost,
-                    multiplicity=multiplicity, params=p,
+                    multiplicity=multiplicity, params=_recurrent_params(eqn),
                     in_shapes=tuple(tuple(v.aval.shape) for v in eqn.invars
                                     if hasattr(v, "aval")),
                     out_shapes=tuple(tuple(v.aval.shape)
                                      for v in eqn.outvars),
                     dtype=_dtype_of(eqn)))
-            else:
-                _walk(body, ops, multiplicity * length)
             continue
 
-        cost, cparams = costmodel.eqn_cost(eqn)
-        if prim == "dot_general":
-            kind, params = _classify_dot(eqn, cparams)
-        elif prim == "conv_general_dilated":
-            kind, params = "conv2d", _classify_conv(eqn)
+        if prim == "pallas_call" or _sub_jaxpr(eqn) is not None:
+            # a call this walk does not enter: priced as one elementwise op
+            with telemetry.span("trace.priced_default"):
+                _append_op(eqn, prim, ops, multiplicity)
         else:
-            kind, params = prim, dict(cparams)
-        ops.append(Op(
-            name=prim, kind=kind, cost=cost, multiplicity=multiplicity,
-            params=params,
-            in_shapes=tuple(tuple(v.aval.shape) for v in eqn.invars
-                            if hasattr(v, "aval")
-                            and not isinstance(v, jcore.Literal)),
-            out_shapes=tuple(tuple(v.aval.shape) for v in eqn.outvars),
-            dtype=_dtype_of(eqn)))
+            _append_op(eqn, prim, ops, multiplicity)
+
+
+def _append_op(eqn, prim: str, ops: List[Op], multiplicity: int) -> None:
+    cost, cparams = costmodel.eqn_cost(eqn)
+    if prim == "dot_general":
+        kind, params = _classify_dot(eqn, cparams)
+    elif prim == "conv_general_dilated":
+        kind, params = "conv2d", _classify_conv(eqn)
+    else:
+        kind, params = prim, dict(cparams)
+    ops.append(Op(
+        name=prim, kind=kind, cost=cost, multiplicity=multiplicity,
+        params=params,
+        in_shapes=tuple(tuple(v.aval.shape) for v in eqn.invars
+                        if hasattr(v, "aval")
+                        and not isinstance(v, jcore.Literal)),
+        out_shapes=tuple(tuple(v.aval.shape) for v in eqn.outvars),
+        dtype=_dtype_of(eqn)))
 
 
 def _dtype_of(eqn) -> str:
@@ -805,9 +870,10 @@ class OperationTracker:
 
     def track(self, fn, *args, label: str = "iteration",
               **kwargs) -> TrackedTrace:
-        closed = jax.make_jaxpr(fn)(*args, **kwargs)
-        ops: List[Op] = []
-        _walk(closed.jaxpr, ops, 1)
+        with telemetry.span("trace.track"):
+            closed = jax.make_jaxpr(fn)(*args, **kwargs)
+            ops: List[Op] = []
+            _walk(closed.jaxpr, ops, 1)
         trace = TrackedTrace(ops=ops, origin_device=self.origin_device,
                              label=label)
         return trace.measure(self.measure_method)

@@ -30,14 +30,16 @@ def _mask(qpos, kpos, causal: bool, window) -> jnp.ndarray:
 
 def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, window: int = 0,
-                    q_offset: int = 0) -> jnp.ndarray:
+                    q_offset: int = 0,
+                    scale: Optional[float] = None) -> jnp.ndarray:
     """Reference attention materializing the score matrix.
 
-    q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H = KV * rep."""
+    q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H = KV * rep.
+    ``scale`` multiplies the scores (default 1/sqrt(hd))."""
     b, sq, h, hd = q.shape
     skv, kv = k.shape[1], k.shape[2]
     rep = h // kv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     qr = q.reshape(b, sq, kv, rep, hd).astype(jnp.float32)
     s = jnp.einsum("bqkrd,bskd->bkrqs", qr, k.astype(jnp.float32)) * scale
     qpos = q_offset + jnp.arange(sq)
@@ -52,7 +54,8 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, window: int = 0,
                     q_offset: int = 0, chunk_q: int = 1024,
-                    chunk_kv: int = 1024) -> jnp.ndarray:
+                    chunk_kv: int = 1024,
+                    scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax chunked attention (never materializes Sq x Skv).
 
     Block-sparsity (§Perf hillclimb #2): q chunks iterate in a *python*
@@ -61,11 +64,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``window`` is a static python int > 0, past blocks outside the sliding
     window are statically skipped too (banded attention: O(S·W) instead of
     O(S²) — this is what makes gemma3's 5 local layers per global layer
-    cheap at 32k)."""
+    cheap at 32k).  ``scale`` as in :func:`dense_attention`."""
     b, sq, h, hd = q.shape
     skv, kv = k.shape[1], k.shape[2]
     rep = h // kv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     cq = min(chunk_q, sq)
     ckv = min(chunk_kv, skv)
     # pad to multiples

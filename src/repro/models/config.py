@@ -40,6 +40,21 @@ class ModelConfig:
     ssm_chunk: int = 256
     # hybrid (zamba2): one *shared* attention block applied every N layers
     attn_every: int = 0
+    # granitemoehybrid (granite 4.0-h): a mixer per layer ("mamba" or
+    # "attention"), each followed by routed experts and one shared expert
+    # of width ``shared_ff``; the published multipliers scale the
+    # embedding, each residual branch, the attention scores (0 ->
+    # 1/sqrt(head_dim)) and divide the logits
+    layer_types: Tuple[str, ...] = ()
+    shared_ff: int = 0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    # expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + n_experts_local) and routes over all n_experts
+    n_experts_local: int = 0    # 0 -> all of them
+    expert_offset: int = 0
     # modality frontend (stub): precomputed patch/frame embeddings
     frontend: str = ""          # "" | "vision" | "audio"
     frontend_prefix_len: int = 0
@@ -75,6 +90,20 @@ class ModelConfig:
         return 1
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_local or self.n_experts
+
+    @property
+    def layer_period(self) -> int:
+        """The shortest repeat of ``layer_types`` that tiles the stack."""
+        n, kinds = self.n_layers, self.layer_types
+        for p in range(1, n + 1):
+            if n % p == 0 and all(kinds[i] == kinds[i % p]
+                                  for i in range(n)):
+                return p
+        return n
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -84,9 +113,30 @@ class ModelConfig:
         return (self.family in ("ssm", "hybrid")
                 or (self.sliding_window > 0 and self.global_every > 0))
 
+    def _granite_layer_params(self) -> Tuple[int, int]:
+        """(mamba layer, attention layer) parameters of granitemoehybrid:
+        two norms, the mixer, the router over every expert, the experts
+        held here and the shared expert."""
+        d, hd = self.d_model, self.resolved_head_dim
+        di, n, h = self.d_inner, self.ssm_state, self.ssm_heads
+        conv_dim = di + 2 * self.ssm_groups * n
+        mamba = (d * (2 * di + 2 * self.ssm_groups * n + h)   # in_proj
+                 + (self.ssm_conv + 1) * conv_dim               # conv + bias
+                 + 3 * h + di + di * d)      # dt_bias, a_log, d; norm; out
+        attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
+            + self.n_heads * hd * d
+        ffn = (d * self.n_experts + self.experts_held * 3 * d * self.d_ff
+               + 3 * d * self.shared_ff + 2 * d)
+        return mamba + ffn, attn + ffn
+
     def n_params(self) -> float:
         """Total parameter count (embedding included)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
+        if self.family == "granitemoehybrid":
+            mamba, attn = self._granite_layer_params()
+            return (v * d * (1 if self.tie_embeddings else 2) + d
+                    + sum(attn if k == "attention" else mamba
+                          for k in self.layer_types[:self.n_layers]))
         hd = self.resolved_head_dim
         emb = v * d * (1 if self.tie_embeddings else 2)
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
@@ -119,6 +169,9 @@ class ModelConfig:
         if not self.n_experts:
             return self.n_params()
         d, f = self.d_model, self.d_ff
+        if self.family == "granitemoehybrid":
+            return self.n_params() + self.n_layers * (
+                self.top_k - self.experts_held) * 3 * d * f
         dense_equiv = dataclasses.replace(self, n_experts=0, top_k=0)
         base = dense_equiv.n_params() - self.n_layers * 3 * d * f
         return base + self.n_layers * (self.top_k * 3 * d * f

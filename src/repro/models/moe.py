@@ -27,13 +27,21 @@ from repro.models.layers import init_dense
 from repro.parallel import ctx
 
 
-def init_moe(key, d_model: int, d_ff: int, n_experts: int, dtype):
+def init_moe(key, d_model: int, d_ff: int, n_experts: int, dtype,
+             n_local: Optional[int] = None):
+    """A router over ``n_experts`` and the weights of all of them, or,
+    given ``n_local``, of an expert-parallel share of that many: a
+    share's matrices are scaled by their own fan-in (``d_model``,
+    ``d_ff``), so that an expert is drawn alike whatever the share's
+    size (the whole layer's default scale is ``1/sqrt(n_experts)``)."""
     ks = jax.random.split(key, 4)
+    e, up, down = ((n_experts, None, None) if n_local is None
+                   else (n_local, d_model ** -0.5, d_ff ** -0.5))
     return {
         "router": init_dense(ks[0], (d_model, n_experts), dtype),
-        "w_gate": init_dense(ks[1], (n_experts, d_model, d_ff), dtype),
-        "w_up": init_dense(ks[2], (n_experts, d_model, d_ff), dtype),
-        "w_down": init_dense(ks[3], (n_experts, d_ff, d_model), dtype),
+        "w_gate": init_dense(ks[1], (e, d_model, d_ff), dtype, scale=up),
+        "w_up": init_dense(ks[2], (e, d_model, d_ff), dtype, scale=up),
+        "w_down": init_dense(ks[3], (e, d_ff, d_model), dtype, scale=down),
     }
 
 
@@ -115,3 +123,69 @@ def moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
     ce = jax.nn.one_hot(top_e.reshape(t, top_k)[:, 0], n_experts).mean(0)
     aux = aux_weight * n_experts * jnp.sum(me * ce)
     return combined.reshape(b, s, d), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Expert-parallel share with top-k softmax gating (granitemoehybrid)
+# ---------------------------------------------------------------------------
+def topk_softmax_gate(logits: jnp.ndarray, top_k: int
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Granite's gate: the ``top_k`` largest router logits of each token
+    and a softmax over those alone.  Returns (gates, experts), (..., K)."""
+    top_l, top_e = jax.lax.top_k(logits, top_k)
+    return jax.nn.softmax(top_l, axis=-1), top_e
+
+
+def capacity_slots(top_e: jnp.ndarray, n_experts: int,
+                   capacity: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The capacity rule of :func:`moe_layer`: each (token, k) assignment,
+    token-major, takes the next slot of its expert; slots past
+    ``capacity`` are dropped.  Returns (slot, kept), (T*K,)."""
+    flat_e = top_e.reshape(-1)
+    onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
+    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
+                              flat_e[:, None], 1)[:, 0]
+    return pos, pos < capacity
+
+
+def local_moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
+                    expert_offset: int = 0,
+                    capacity_factor: float = 1.25) -> jnp.ndarray:
+    """The part of a top-k MoE layer that one expert-parallel share gives.
+
+    ``params["router"]`` is (D, E) over every expert; the expert weights
+    hold this share's ``n_local`` experts, ``expert_offset`` onward.  The
+    gate is :func:`topk_softmax_gate`, the capacity that of the whole
+    layer (``round(T * K / E * capacity_factor)`` slots an expert, filled
+    in token order).  Only assignments to the held experts are computed:
+    gathered into (n_local, C, D) by a slot table, run through the SwiGLU
+    experts and scattered back weighted by their gates.  The shares'
+    outputs sum to the whole layer's.  x: (B, S, D) -> (B, S, D)."""
+    b, s, d = x.shape
+    t = b * s
+    n_experts = params["router"].shape[1]
+    n_local = params["w_gate"].shape[0]
+    capacity = int(max(1, round(t * top_k / n_experts * capacity_factor)))
+    xt = x.reshape(t, d)
+    logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+    gates, top_e = topk_softmax_gate(logits, top_k)         # (T, K)
+    pos, kept = capacity_slots(top_e, n_experts, capacity)
+    local = top_e.reshape(-1) - expert_offset
+    kept = kept & (local >= 0) & (local < n_local)
+    n_slots = n_local * capacity
+    slot = jnp.where(kept, local * capacity + pos, n_slots)  # drop -> spare
+    token = jnp.repeat(jnp.arange(t, dtype=jnp.int32), top_k)
+    # slot -> token (t: the zero row) and its gate; the spare slot is cut
+    slot_token = jnp.full((n_slots + 1,), t, jnp.int32).at[slot].set(
+        token)[:n_slots]
+    slot_gate = jnp.zeros((n_slots + 1,), jnp.float32).at[slot].set(
+        gates.reshape(-1))[:n_slots]
+    xz = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])
+    dispatched = xz[slot_token].reshape(n_local, capacity, d)
+    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatched,
+                                  params["w_gate"]))
+    up = jnp.einsum("ecd,edf->ecf", dispatched, params["w_up"])
+    out = jnp.einsum("ecf,efd->ecd", gate * up, params["w_down"])
+    weighted = out.reshape(n_slots, d) * slot_gate[:, None].astype(x.dtype)
+    combined = jnp.zeros((t + 1, d), x.dtype).at[slot_token].add(weighted)
+    return combined[:t].reshape(b, s, d)

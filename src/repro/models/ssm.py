@@ -18,8 +18,13 @@ import jax.numpy as jnp
 from repro.models.layers import init_dense, rms_norm
 
 
-def ssd_reference(x, dt, a, b, c, d_skip=None):
-    """Sequential SSD recurrence: S_t = S_{t-1} exp(dt_t A) + dt_t B_t x_t."""
+def ssd_reference(x, dt, a, b, c, d_skip=None, initial_state=None,
+                  return_final=False):
+    """Sequential SSD recurrence: S_t = S_{t-1} exp(dt_t A) + dt_t B_t x_t.
+
+    ``initial_state`` (B, H, N, P) continues a sequence from an earlier
+    call's final state (``return_final``), so that a long one can be run
+    in pieces."""
     bs, l, h, p = x.shape
     n = b.shape[-1]
     g = b.shape[2]
@@ -34,15 +39,18 @@ def ssd_reference(x, dt, a, b, c, d_skip=None):
         y = jnp.einsum("bhn,bhnp->bhp", ct, s)
         return s, y
 
-    s0 = jnp.zeros((bs, h, n, p), jnp.float32)
+    s0 = (jnp.zeros((bs, h, n, p), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
     xs = (x.transpose(1, 0, 2, 3).astype(jnp.float32),
           dt.transpose(1, 0, 2).astype(jnp.float32),
           bh.transpose(1, 0, 2, 3).astype(jnp.float32),
           ch.transpose(1, 0, 2, 3).astype(jnp.float32))
-    _, ys = jax.lax.scan(step, s0, xs)
+    s_final, ys = jax.lax.scan(step, s0, xs)
     y = ys.transpose(1, 0, 2, 3)
     if d_skip is not None:
         y = y + d_skip[None, None, :, None] * x.astype(jnp.float32)
+    if return_final:
+        return y.astype(x.dtype), s_final
     return y.astype(x.dtype)
 
 

@@ -9,6 +9,11 @@ One functional model with ``init_params`` / ``forward`` / ``loss_fn`` /
   * ssm    : Mamba2 (SSD) blocks.
   * hybrid : zamba2-style supercells — ``attn_every`` Mamba2 layers followed
     by one application of a *weight-shared* attention+MLP block.
+  * granitemoehybrid : granite 4.0-h — a mixer per layer (Mamba2, or NoPE
+    GQA), each followed by this chip's share of the routed experts and a
+    shared expert, with the published multipliers; one period of
+    ``layer_types`` is unrolled in a ``lax.scan`` body over the periods.
+    Training only (no prefill or decode path).
 
 VLM / audio frontends are stubs per the assignment: ``prefix_embeds``
 (precomputed patch/frame embeddings) are linearly projected and prepended.
@@ -93,6 +98,23 @@ def _stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
+def _init_granite_layer(key, cfg: ModelConfig, kind: str, dtype) -> Dict:
+    d, sf = cfg.d_model, cfg.shared_ff
+    ks = jax.random.split(key, 5)
+    blk = {"ln1": jnp.ones((d,), dtype), "ln2": jnp.ones((d,), dtype),
+           "moe": moe_mod.init_moe(ks[1], d, cfg.d_ff, cfg.n_experts, dtype,
+                                   n_local=cfg.experts_held),
+           "shared": {"w_gate": init_dense(ks[2], (d, sf), dtype),
+                      "w_up": init_dense(ks[3], (d, sf), dtype),
+                      "w_down": init_dense(ks[4], (sf, d), dtype)}}
+    if kind == "mamba":
+        blk["mamba"] = ssm_mod.init_mamba_block(ks[0], cfg, dtype)
+    else:
+        attn = _init_attn_block(ks[0], cfg, dtype)
+        blk.update({k: attn[k] for k in ("wq", "wk", "wv", "wo")})
+    return blk
+
+
 def init_params(cfg: ModelConfig, key) -> Dict:
     dtype = _dtype(cfg)
     keys = jax.random.split(key, cfg.n_layers + 8)
@@ -114,6 +136,13 @@ def init_params(cfg: ModelConfig, key) -> Dict:
                                                             dtype)})
                   for i in range(cfg.n_layers)]
         params["layers"] = _stack(blocks)
+    elif cfg.family == "granitemoehybrid":
+        # params["layers"][j]: the j-th layer of every period, stacked
+        period = cfg.layer_period
+        blocks = [_init_granite_layer(keys[i], cfg, cfg.layer_types[i],
+                                      dtype) for i in range(cfg.n_layers)]
+        params["layers"] = [_stack(blocks[j::period])
+                            for j in range(period)]
     elif cfg.family == "hybrid":
         n_groups = cfg.n_layers // cfg.attn_every
         blocks = [dict(ln=jnp.ones((cfg.d_model,), dtype),
@@ -201,6 +230,61 @@ def _mamba_layer(layer: Dict, x: jnp.ndarray, cfg: ModelConfig,
     return _shard_act(x + ssm_mod.mamba_block(layer["mamba"], hn, cfg))
 
 
+def _granite_attention(blk: Dict, x: jnp.ndarray, cfg: ModelConfig):
+    """GQA with no position embedding, scores times attention_multiplier."""
+    b, s, _ = x.shape
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    q = (x @ blk["wq"]).reshape(b, s, h, hd)
+    k = (x @ blk["wk"]).reshape(b, s, kv, hd)
+    v = (x @ blk["wv"]).reshape(b, s, kv, hd)
+    scale = cfg.attention_multiplier or None
+    if cfg.use_flash and s > cfg.attn_chunk_q:
+        o = attn_mod.flash_attention(q, k, v, causal=True,
+                                     chunk_q=cfg.attn_chunk_q,
+                                     chunk_kv=cfg.attn_chunk_kv, scale=scale)
+    else:
+        o = attn_mod.dense_attention(q, k, v, causal=True, scale=scale)
+    return o.reshape(b, s, h * hd) @ blk["wo"]
+
+
+def granite_layer(blk: Dict, x: jnp.ndarray, cfg: ModelConfig,
+                  kind: str) -> jnp.ndarray:
+    """One granitemoehybrid layer: pre-norm mixer and pre-norm (routed
+    share + shared expert), each branch scaled by residual_multiplier."""
+    hn = rms_norm(x, blk["ln1"], cfg.norm_eps)
+    mix = (ssm_mod.mamba_block(blk["mamba"], hn, cfg) if kind == "mamba"
+           else _granite_attention(blk, hn, cfg))
+    x = _shard_act(x + mix * cfg.residual_multiplier)
+    hn = rms_norm(x, blk["ln2"], cfg.norm_eps)
+    sh = blk["shared"]
+    ffn = (moe_mod.local_moe_layer(blk["moe"], hn, cfg.top_k,
+                                   cfg.expert_offset, cfg.capacity_factor)
+           + swiglu(hn, sh["w_gate"], sh["w_up"], sh["w_down"]))
+    return _shard_act(x + ffn * cfg.residual_multiplier)
+
+
+def _granite_forward(params: Dict, cfg: ModelConfig, tokens: jnp.ndarray):
+    x = _shard_act(params["embed"][tokens]
+                   * jnp.asarray(cfg.embedding_multiplier, _dtype(cfg)))
+    layers = []
+    for kind in cfg.layer_types[:cfg.layer_period]:
+        fn = partial(granite_layer, cfg=cfg, kind=kind)
+        # per-layer remat: a layer's SSD chunk tensors are rebuilt in its
+        # own backward, so only one layer's are live at a time
+        layers.append(jax.checkpoint(fn) if cfg.remat else fn)
+
+    def period(carry, blocks):
+        for fn, blk in zip(layers, blocks):
+            carry = fn(blk, carry)
+        return carry, None
+
+    x, _ = jax.lax.scan(period, x, params["layers"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = (x @ head) / jnp.asarray(cfg.logits_scaling, x.dtype)
+    return ctx.constrain(logits, "batch", None, "model")
+
+
 # ---------------------------------------------------------------------------
 # Forward (training)
 # ---------------------------------------------------------------------------
@@ -217,6 +301,9 @@ def forward(params: Dict, cfg: ModelConfig, tokens: jnp.ndarray,
             prefix_embeds: Optional[jnp.ndarray] = None
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (logits over the token positions, aux losses)."""
+    if cfg.family == "granitemoehybrid":
+        return (_granite_forward(params, cfg, tokens),
+                jnp.zeros((), jnp.float32))
     x = _embed_inputs(params, cfg, tokens, prefix_embeds)
     b, s, d = x.shape
     positions = jnp.arange(s)[None, :].repeat(b, 0)

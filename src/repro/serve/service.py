@@ -87,7 +87,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, \
 
 from repro import telemetry
 from repro.core import integrity
-from repro.core.batched import env_float, env_int
+from repro.core.batched import SCORER_ROWS, env_float, env_int
 from repro.core.trace import TrackedTrace
 from repro.serve import faults
 from repro.serve import snapshot as snapshot_mod
@@ -1058,6 +1058,7 @@ class PredictionService:
                              if self._snapshot is not None
                              else snapshot_mod.empty_stats()),
                 "faults": faults.stats(),
+                "scorer_rows": SCORER_ROWS.snapshot(),
                 "spans": telemetry.stats()}
 
     # -- coalescing core ----------------------------------------------------

@@ -57,6 +57,11 @@ NAMES = (
     "engine.score",     # one scorer call: transfer, launch, readback
     "trace.decode_slow",  # a trace document decoded op by op
     "trace.ops_built",  # a column-decoded trace's ops built on first read
+    "trace.track",      # one OperationTracker.track: trace and walk a step
+    "trace.jit_walked",  # a nested jit call walked in place
+    "trace.scan_walked",  # a scan walked as trip count x its body
+    "trace.scan_recurrent",  # a scan kept as one recurrent op
+    "trace.priced_default",  # a call or kernel priced as one elementwise op
     "gc.pause",         # a collector pause (install_gc_hook)
 )
 GC_PAUSE = "gc.pause"

@@ -250,8 +250,9 @@ def server():
 def test_one_rank_moves_each_rank_path_span_by_one(server):
     client = PredictionClient(server.url)
     client.rank(_trace(24), 8)          # compile the scorer's bucket
+    trace = _trace(25)                  # tracking moves trace.track
     before = _counts(client.stats()["spans"])
-    client.rank(_trace(25), 8)
+    client.rank(trace, 8)
     after = _counts(client.stats()["spans"])
     moved = {n: after[n] - before[n] for n in telemetry.NAMES
              if n != "gc.pause"}
@@ -264,12 +265,13 @@ def test_a_profiled_rank_has_one_phase_at_a_time(server, tmp_path):
     spans carry its id and the batch its engine pass ran in."""
     client = PredictionClient(server.url)
     client.rank(_trace(26), 8)
+    trace = _trace(27)                  # tracked outside the profile
 
     def rank_until_replied():
         # the client can read the answer before the handler has closed
         # its ``http.reply`` span: wait for it inside the profile
         replies = telemetry.stats()["http.reply"]["count"]
-        client.rank(_trace(27), 8)
+        client.rank(trace, 8)
         deadline = time.monotonic() + 10
         while (telemetry.stats()["http.reply"]["count"] == replies
                and time.monotonic() < deadline):

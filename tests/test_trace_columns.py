@@ -146,6 +146,8 @@ def _malformed(doc, case, monkeypatch):
         linear["params"]["batch"] = True
     elif case == "non_dict_op":
         ops[1] = [1, 2]
+    elif case == "int_past_float":
+        ops[0]["cost"]["flops"] = 10 ** 400
     elif case == "over_op_cap":
         monkeypatch.setenv("REPRO_TRACE_MAX_OPS", str(len(ops) - 1))
     return doc
@@ -154,7 +156,7 @@ def _malformed(doc, case, monkeypatch):
 @pytest.mark.parametrize("case", [
     "missing_field", "bool_number", "numeric_string", "nan",
     "negative_time", "fractional_multiplicity", "non_list_shape",
-    "bool_feature_param", "non_dict_op", "over_op_cap"])
+    "bool_feature_param", "non_dict_op", "int_past_float", "over_op_cap"])
 def test_malformed_documents_fall_back_and_raise_as_before(
         case, small_doc, monkeypatch):
     doc = _malformed(small_doc, case, monkeypatch)
