@@ -249,6 +249,7 @@ def main(argv=None) -> None:
     out["memory_peak_bytes"] = (dev.memory_stats() or {}).get(
         "peak_bytes_in_use")
     out["tolerances"] = TOLERANCES
+
     def flat(step, layers):
         return {"loss": step["loss"],
                 **{f"step.{k}": v for k, v in step.items() if k != "loss"},
