@@ -53,14 +53,16 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 #: and the Mamba decays exp(dt A), |A| up to 16, so the whole step's
 #: gradients move by tens of percent at random init while float8
 #: weights (4 significant bits) move them by most of their norm.  The
-#: layer-by-layer errors do not compound through the stack.
+#: layer-by-layer errors do not compound through the stack: on a v5e at
+#: seed 1616000001 bfloat16 reads at most 0.0124 in the attention layer
+#: and 0.0144 in the Mamba-2 layers, float8 at least 0.022 and 0.135.
 TOLERANCES = {"loss": 1e-3,
               "step.mamba.in_proj": 0.5, "step.attention.wq": 0.5,
               "step.experts.w_down": 0.5, "step.router": 0.5,
               "step.shared.w_up": 0.5, "step.embed": 0.5,
-              "layer.attention.update": 0.05,
-              "layer.attention.input_grad": 0.05,
-              "layer.attention.mixer_grad": 0.05,
+              "layer.attention.update": 0.02,
+              "layer.attention.input_grad": 0.02,
+              "layer.attention.mixer_grad": 0.02,
               "layer.mamba.update": 0.08, "layer.mamba.input_grad": 0.08,
               "layer.mamba.mixer_grad": 0.08}
 SSD_BLOCK = 256

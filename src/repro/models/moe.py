@@ -58,6 +58,18 @@ def _dispatch_groups(t: int) -> int:
     return max(g, 1)
 
 
+def capacity_slots(flat_e: jnp.ndarray, n_experts: int,
+                   capacity: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The capacity rule: within each group (the leading axes of
+    ``flat_e``, (..., T*K), the experts of each (token, k) assignment,
+    token-major), an assignment takes the next slot of its expert; slots
+    past ``capacity`` are dropped.  Returns (slot, kept), (..., T*K)."""
+    onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
+    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=-2) - 1,
+                              flat_e[..., None], -1)[..., 0]
+    return pos, pos < capacity
+
+
 def moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
               capacity_factor: float = 1.25,
               aux_weight: float = 0.01,
@@ -80,10 +92,7 @@ def moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
     flat_e = top_e.reshape(g, tg * top_k)                 # (g, T_g*K)
-    onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=1) - 1                  # per-group count
-    flat_pos = jnp.take_along_axis(pos, flat_e[..., None], 2)[..., 0]
-    keep = flat_pos < capacity
+    flat_pos, keep = capacity_slots(flat_e, n_experts, capacity)
     safe_pos = jnp.where(keep, flat_pos, 0)
     token_idx = jnp.repeat(jnp.arange(tg), top_k)         # shared per group
 
@@ -136,16 +145,6 @@ def topk_softmax_gate(logits: jnp.ndarray, top_k: int
     return jax.nn.softmax(top_l, axis=-1), top_e
 
 
-def capacity_slots(top_e: jnp.ndarray, n_experts: int,
-                   capacity: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The capacity rule of :func:`moe_layer`: each (token, k) assignment,
-    token-major, takes the next slot of its expert; slots past
-    ``capacity`` are dropped.  Returns (slot, kept), (T*K,)."""
-    flat_e = top_e.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
-    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
-                              flat_e[:, None], 1)[:, 0]
-    return pos, pos < capacity
 
 
 def local_moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
@@ -169,7 +168,7 @@ def local_moe_layer(params: Dict, x: jnp.ndarray, top_k: int,
     xt = x.reshape(t, d)
     logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
     gates, top_e = topk_softmax_gate(logits, top_k)         # (T, K)
-    pos, kept = capacity_slots(top_e, n_experts, capacity)
+    pos, kept = capacity_slots(top_e.reshape(-1), n_experts, capacity)
     local = top_e.reshape(-1) - expert_offset
     kept = kept & (local >= 0) & (local < n_local)
     n_slots = n_local * capacity

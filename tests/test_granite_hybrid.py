@@ -155,11 +155,17 @@ def test_the_gate_is_a_softmax_over_the_top_k_logits():
 
 def test_the_capacity_rule_fills_slots_in_token_order():
     top_e = jnp.array([[0, 1], [1, 0], [1, 2], [0, 1]])
-    pos, kept = moe_mod.capacity_slots(top_e, 3, capacity=2)
+    pos, kept = moe_mod.capacity_slots(top_e.reshape(-1), 3, capacity=2)
     np.testing.assert_array_equal(np.asarray(pos),
                                   [0, 0, 1, 1, 2, 0, 2, 3])
     np.testing.assert_array_equal(np.asarray(kept),
                                   [1, 1, 1, 1, 0, 1, 0, 0])
+    # moe_layer's groups (leading axes) each count their own slots
+    grouped = jnp.stack([top_e.reshape(-1), top_e.reshape(-1)[::-1]])
+    gpos, _ = moe_mod.capacity_slots(grouped, 3, capacity=2)
+    np.testing.assert_array_equal(np.asarray(gpos[0]), np.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(gpos[1]),
+                                  [0, 0, 0, 1, 1, 2, 3, 2])
 
 
 def test_other_families_are_untouched_by_the_attention_scale():
